@@ -26,6 +26,10 @@ argument for the halo-exchange subsystem in one artifact.
 
     PYTHONPATH=src python -m benchmarks.palgol_mesh [--scale 22]
     PYTHONPATH=src python -m benchmarks.palgol_mesh --comm-only
+
+This is a fake-device CPU tool: it lowers against 512 host-platform
+devices and never runs on a chip. It sets ``XLA_FLAGS`` on import only
+where the caller has not put a device count there already.
 """
 
 import argparse

@@ -21,6 +21,10 @@ def main() -> None:
     args = ap.parse_args()
     sections = set(args.sections.split(","))
 
+    from repro import compile_cache
+
+    compile_cache.enable()
+
     print("name,us_per_call,derived")
     rows = []
     if "table5" in sections:

@@ -44,8 +44,8 @@ def run(scale: int = 10):
         import jax
 
         fused = jax.jit(cp.fn)
-        us_palgol = time_fn(fused, f0, warmup=1, iters=3)
-        dense_out, _ = fused(f0)
+        us_palgol = time_fn(fused, f0, g, warmup=1, iters=3)
+        dense_out, _ = fused(f0, g)
 
         def manual(f0=f0, prog=cp.prog, g=g):
             # the manual baseline has no §4.3 merging/fusion: fuse=False

@@ -14,6 +14,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import compile_program
 from repro.core import algorithms as alg
 from repro.core.logic import pull_rounds, push_rounds
@@ -22,6 +23,7 @@ from repro.pregel import run_bsp
 
 
 def main():
+    compile_cache.enable()
     print("chain-access compilation (paper §4.1.1):")
     for k in (2, 3, 4, 8):
         pat = ("D",) * k

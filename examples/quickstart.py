@@ -9,12 +9,14 @@ computation → execute → compare superstep accounting across compilers.
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import compile_program, interpret
 from repro.core import algorithms as alg
 from repro.graph import generators as G
 
 
 def main():
+    compile_cache.enable()
     # a weighted power-law digraph (RMAT, ~1k vertices)
     g = G.rmat(10, avg_degree=8, directed=True, weighted=True, seed=7)
     print(f"graph: {g.n_vertices} vertices, {int(np.asarray(g.edge_mask).sum())} edges")

@@ -1,7 +1,7 @@
 """Palgol program compilation: AST → executable JAX + STM cost models.
 
 ``compile_program`` produces a :class:`CompiledProgram` whose ``fn`` is a
-pure, jit-able ``fields → (fields, trips)`` function: fixed-point iterations
+pure, jit-able ``(fields, graph) → (fields, trips)`` function: fixed-point iterations
 become ``lax.while_loop`` (termination via a global any-changed reduction —
 Pregel's OR aggregator), sequences compose, and the whole Palgol program
 traces into a single XLA computation. ``trips`` counts body executions per
@@ -12,6 +12,7 @@ paper's Table-5 accounting.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional, Union
 
 import jax
@@ -96,8 +97,8 @@ class CompiledProgram:
                 fields[name] = jnp.asarray(user_fields[name])
         return fields
 
-    def fn(self, fields: Dict[str, jax.Array], graph=None):
-        """Pure program function: fields → (fields, trips[i32[n_iters]]).
+    def fn(self, fields: Dict[str, jax.Array], graph):
+        """Pure program function: (fields, graph) → (fields, trips[i32[n_iters]]).
 
         Folds the (by default fused) :class:`~repro.core.plan.ProgramPlan`
         into one trace: superstep parts execute in plan order against the
@@ -105,11 +106,11 @@ class CompiledProgram:
         buffers ride the ``lax.while_loop`` carry — the loop-back edge of
         §4.3.2 iteration fusion, traced for real.
 
-        ``graph`` overrides the compile-time graph *data* (same static
-        shape), making the graph a traced argument — required when lowering
-        against a device mesh (closure arrays would bake in as constants).
+        ``graph`` is a traced argument (the compile-time graph or any graph
+        of the same static shape): a closed-over graph would be embedded in
+        the HLO as constants, which at chip scale exceeds the 2 GB
+        serialization limit.
         """
-        graph = graph if graph is not None else self.graph
         pp = self.program_plan()
         trips0 = jnp.zeros((max(self.n_iters, 1),), jnp.int32)
 
@@ -164,6 +165,11 @@ class CompiledProgram:
         out_fields, _, trips = run_items(pp.items, dict(fields), {}, trips0)
         return out_fields, trips
 
+    @functools.cached_property
+    def _jitted_fn(self):
+        # one jit per program: repeated runs reuse its compiled executable
+        return jax.jit(self.fn)
+
     def run(
         self,
         user_fields: Optional[Dict[str, jax.Array]] = None,
@@ -171,8 +177,8 @@ class CompiledProgram:
     ):
         """Execute; returns (fields, trips, superstep counts per regime)."""
         fields = self.init_fields(user_fields)
-        fn = jax.jit(self.fn) if jit else self.fn
-        out, trips = fn(fields)
+        fn = self._jitted_fn if jit else self.fn
+        out, trips = fn(fields, self.graph)
         trips_host = [int(x) for x in trips]
         counts = {
             name: cm.count(trips_host) for name, cm in self.cost_models.items()

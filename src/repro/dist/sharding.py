@@ -53,8 +53,6 @@ from typing import Any, Optional, Sequence, Tuple, Union
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.dist import compat  # noqa: F401  (installs jax mesh-API shims)
-
 # --------------------------------------------------------------------------
 # logical axes
 

@@ -88,17 +88,21 @@ def rmat(
     n = 1 << n_log2
     m = int(n * avg_degree)
     rng = np.random.default_rng(seed)
-    src = np.zeros(m, dtype=np.int64)
-    dst = np.zeros(m, dtype=np.int64)
+    src = np.zeros(m, dtype=np.int32)
+    dst = np.zeros(m, dtype=np.int32)
+    r = np.empty(m)
+    bit = np.empty(m, dtype=np.int32)
     for level in range(n_log2):
-        r = rng.random(m)
+        rng.random(out=r)
         # quadrant probabilities a,b,c,d
         go_right = (r >= a) & (r < a + b) | (r >= a + b + c)
         go_down = r >= a + b
-        src |= go_down.astype(np.int64) << level
-        dst |= go_right.astype(np.int64) << level
+        np.left_shift(go_down, level, out=bit, dtype=np.int32, casting="unsafe")
+        src |= bit
+        np.left_shift(go_right, level, out=bit, dtype=np.int32, casting="unsafe")
+        dst |= bit
     keep = src != dst
-    src, dst = src[keep].astype(np.int32), dst[keep].astype(np.int32)
+    src, dst = src[keep], dst[keep]
     w = rng.uniform(0.1, 10.0, size=src.shape).astype(np.float32) if weighted else None
     if directed:
         return from_edge_list(src, dst, n, w)

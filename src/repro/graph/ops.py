@@ -250,7 +250,6 @@ def mp_gather(field: jax.Array, idx: jax.Array, fill=None) -> jax.Array:
     mesh, daxes, n_data = _mp_mesh()
     if mesh is None or n_data == 1:
         return gather(field, idx, fill)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     e = idx.shape[0]
@@ -263,12 +262,12 @@ def mp_gather(field: jax.Array, idx: jax.Array, fill=None) -> jax.Array:
         return gather(f, i, fill)
 
     out_ndim = field.ndim - 1 + idx.ndim
-    out = shard_map(
+    out = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(*(None,) * field.ndim), P(d)),
         out_specs=P(d, *(None,) * (out_ndim - 1)),
-        check_rep=False,
+        check_vma=False,
     )(field, idx_p)
     return out[:e] if e_pad != e else out
 
@@ -312,7 +311,6 @@ def mp_segment_reduce(
     mesh, daxes, n_data = _mp_mesh()
     if mesh is None or n_data == 1:
         return segment_reduce(values, segment_ids, num_segments, op, mask=mask)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     d = _dspec(daxes)
@@ -340,12 +338,12 @@ def mp_segment_reduce(
         raise ValueError(op)
 
     out_ndim = values.ndim
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(d, *(None,) * (values.ndim - 1)), P(d), P(d)),
         out_specs=P(*(None,) * out_ndim),
-        check_rep=False,
+        check_vma=False,
     )(values, segment_ids, mask)
 
 

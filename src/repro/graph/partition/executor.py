@@ -179,7 +179,7 @@ def _local_view(pg: PartitionedGraph) -> PartitionedGraph:
 
 
 def _make_superstep_fn(ss: plan_mod.Superstep, pg: PartitionedGraph, mesh):
-    """jit(shard_map(...)) executing ONE fused superstep's parts in order.
+    """jit(jax.shard_map(...)) executing ONE fused superstep's parts in order.
 
     ``(fields, mailbox, pg) -> (fields, mailbox)`` over per-shard blocks;
     the specs are pytree prefixes (every fields/mailbox leaf is a
@@ -188,7 +188,6 @@ def _make_superstep_fn(ss: plan_mod.Superstep, pg: PartitionedGraph, mesh):
     superstep's collectives (e.g. a RemoteUpdate's reduce-scatter plus the
     next step's halo exchange) land in this one dispatch.
     """
-    from jax.experimental.shard_map import shard_map
 
     tmap = jax.tree_util.tree_map
 
@@ -205,11 +204,11 @@ def _make_superstep_fn(ss: plan_mod.Superstep, pg: PartitionedGraph, mesh):
         )
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(AXIS), P(AXIS), pg_partition_specs(pg)),
             out_specs=(P(AXIS), P(AXIS)),
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -235,7 +234,9 @@ def run_bsp_partitioned(
     dict in, final *dense* fields + superstep count + trips + frontier
     sizes out); the graph is partitioned over ``mesh`` (default: a 1-D
     mesh over all local devices, built by
-    :func:`repro.dist.sharding.shard_mesh`). Every schedule runs here
+    :func:`repro.dist.sharding.shard_mesh`). ``graph`` may already be a
+    :class:`PartitionedGraph` with one shard per mesh device, so a graph
+    partitioned once serves many jobs. Every schedule runs here
     (``pull``/``push``/``naive``/``auto`` — build byte costs from this
     layout with :func:`repro.graph.partition.byte_cost_model`), and
     ``fuse=True`` (default) dispatches the §4.3-fused program plan — one
@@ -251,10 +252,17 @@ def run_bsp_partitioned(
     if mesh is None:
         mesh = shd.shard_mesh(n_shards)
     n_shards = mesh.shape[AXIS]
-    pg = partition_graph(graph, n_shards)
+    if isinstance(graph, PartitionedGraph):
+        if graph.n_shards != n_shards:
+            raise ValueError(
+                f"graph has {graph.n_shards} shards, mesh has {n_shards}"
+            )
+        pg = graph
+    else:
+        pg = partition_graph(graph, n_shards)
     fields = {k: jnp.asarray(v) for k, v in fields.items()}
     if HALTED not in fields:
-        fields[HALTED] = jnp.zeros((graph.n_vertices,), jnp.bool_)
+        fields[HALTED] = jnp.zeros((pg.n_vertices,), jnp.bool_)
     pfields = partition_fields(pg, fields)
     pfields = jax.device_put(
         pfields, shd.vertex_partition_shardings(pfields, mesh)

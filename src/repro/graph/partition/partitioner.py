@@ -112,9 +112,11 @@ def edge_balanced_ranges(graph, n_shards: int) -> np.ndarray:
         )
     dst = np.asarray(graph.dst)[np.asarray(graph.edge_mask)]
     t_src = np.asarray(graph.t_src)[np.asarray(graph.t_mask)]
-    weight = np.ones(n, dtype=np.int64)
-    np.add.at(weight, dst, 1)
-    np.add.at(weight, t_src, 1)
+    weight = (
+        1
+        + np.bincount(dst, minlength=n)[:n]
+        + np.bincount(t_src, minlength=n)[:n]
+    )
     cum = np.cumsum(weight)
     total = int(cum[-1])
     bounds = np.zeros(n_shards + 1, dtype=np.int64)
@@ -173,17 +175,16 @@ def _build_halo(
             send_local[i, j, :c] = ids - bounds[i]
             recv_pos[j, i, :c] = np.arange(lo, lo + c)
 
-    # halo-local remap of the neighbor endpoints
+    # halo-local remap of the neighbor endpoints: a dense id → ghost-slot
+    # table (every foreign neighbor is in its shard's ghost list)
     nbr_halo = np.full(nbr_global.shape, v_max + H, dtype=np.int32)
+    slot = np.zeros(n, dtype=np.int64)
     for s in range(S):
         m = emask[s]
         g = nbr_global[s][m]
         own = (g >= bounds[s]) & (g < bounds[s + 1])
-        loc = np.where(
-            own,
-            g - bounds[s],
-            v_max + np.searchsorted(ghosts[s], g),
-        )
+        slot[ghosts[s]] = np.arange(len(ghosts[s]))
+        loc = np.where(own, g - bounds[s], v_max + slot[g])
         nbr_halo[s, m] = loc.astype(np.int32)
     return (ghost_ids, send_local, recv_pos, H, Hp), nbr_halo
 

@@ -75,8 +75,32 @@ class Graph:
         raise ValueError(f"unknown edge direction {direction!r}")
 
 
+def stable_argsort(key) -> np.ndarray:
+    """``np.argsort(key, kind="stable")`` for integer keys, several times
+    faster on large edge lists.
+
+    Each pass value-sorts ``(digit << 32) | position`` composites: they are
+    unique, so any sort order is the stable one, and numpy's SIMD value
+    sort beats its stable argsort. One pass per 31-bit key digit, least
+    significant first (LSD radix order)."""
+    key = np.asarray(key, np.int64)
+    if key.size >= 1 << 32:
+        return np.argsort(key, kind="stable")
+    if key.size:
+        key = key - key.min()
+    pos = np.arange(key.size, dtype=np.int64)
+    bits = max(int(key.max(initial=0)).bit_length(), 1)
+    order = None
+    for shift in range(0, bits, 31):
+        k = key if order is None else key[order]
+        digit = (k >> shift) & ((1 << 31) - 1)
+        o = np.sort((digit << 32) | pos) & 0xFFFFFFFF
+        order = o if order is None else order[o]
+    return order
+
+
 def _sort_by(key: np.ndarray, *arrays: np.ndarray):
-    order = np.argsort(key, kind="stable")
+    order = stable_argsort(key)
     return tuple(a[order] for a in arrays)
 
 
@@ -149,8 +173,11 @@ def symmetrize(src, dst, weight=None):
     w = np.concatenate([weight, weight])
     # dedup parallel edges, keep first weight
     key = a * (max(int(b.max(initial=0)) + 1, 1)) + b
-    _, idx = np.unique(key, return_index=True)
-    idx.sort()
+    order = stable_argsort(key)
+    ks = key[order]
+    first = np.ones(ks.shape, bool)
+    np.not_equal(ks[1:], ks[:-1], out=first[1:])
+    idx = np.sort(order[first])
     return a[idx].astype(np.int32), b[idx].astype(np.int32), w[idx]
 
 

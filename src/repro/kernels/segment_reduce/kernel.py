@@ -13,7 +13,9 @@ accumulates partial sums across edge sub-blocks, written out once.
 
 Padding slots carry local id = nb (one-hot row of zeros ⇒ no contribution).
 VMEM per step: eb·D (vals) + eb (ids) + nb·eb (one-hot) + nb·D (scratch);
-with eb=256, nb=256, D=128, f32: ~0.5 MB.
+with eb=1024, nb=256, D=128, f32: ~2 MB. On the TPU the 1-D id block must
+be a multiple of 1024, the tiling XLA gives a 1-D int32 array (T(1024));
+interpret mode takes any ``eb``.
 
 The one-hot matmul costs 2·eb·nb·D flops vs the scatter's eb·D — a
 deliberate flops-for-regularity trade: on TPU the MXU delivers those flops
@@ -28,6 +30,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+#: XLA's tile for a 1-D int32 array on the TPU; the id block must be a
+#: multiple of it
+ID_TILE = 1024
 
 
 def _segsum_kernel(ids_ref, vals_ref, o_ref, acc_ref, *, nb, eb, n_e):
@@ -59,13 +65,19 @@ def segment_sum_ell_kernel(
     n_blocks: int,
     nb: int,
     budget: int,
-    eb: int = 256,
+    eb: int = 1024,
     out_dtype=None,
     interpret: bool = False,
 ) -> jax.Array:
     d = vals.shape[1]
     eb = min(eb, budget)
-    assert budget % eb == 0
+    if budget % eb:
+        raise ValueError(f"budget {budget} is not a multiple of eb={eb}")
+    if not interpret and eb % ID_TILE:
+        raise ValueError(
+            f"eb={eb}: on the TPU the id block must be a multiple of "
+            f"{ID_TILE} (1-D int32 tiling)"
+        )
     n_e = budget // eb
     out_dtype = out_dtype or vals.dtype
     from jax.experimental.pallas import tpu as pltpu

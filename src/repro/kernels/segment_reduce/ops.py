@@ -30,7 +30,7 @@ def build_ell_layout(
     segment_ids: jax.Array,
     num_segments: int,
     nb: int = 256,
-    eb: int = 256,
+    eb: int = 1024,
     budget_cap: Optional[int] = None,
 ):
     """Compute (slot permutation, budget, n_blocks) for the ELL layout.
@@ -92,7 +92,7 @@ def segment_sum_ell(
     num_segments: int,
     mask: Optional[jax.Array] = None,
     nb: int = 256,
-    eb: int = 256,
+    eb: int = 1024,
     budget_cap: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:

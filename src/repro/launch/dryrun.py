@@ -1,14 +1,3 @@
-import os
-# NOTE: while-loop LICM is disabled because XLA:CPU shadows every bf16 dot
-# operand with an f32 convert; LICM hoists those converts out of the scan
-# loops, materializing f32 copies of whole [L,B,S,D] remat stacks. TPU has
-# native bf16 MXU input, so the hoisted copies don't exist there — disabling
-# the pass makes the CPU memory analysis TPU-faithful.
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=512 "
-    "--xla_disable_hlo_passes=while-loop-invariant-code-motion"
-)
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 For each cell we build abstract parameters (ShapeDtypeStructs — zero host
@@ -24,7 +13,23 @@ partitioned HLO.
 
 Results land in experiments/dryrun/<mesh>/<arch>__<shape>.json and are
 summarized into EXPERIMENTS.md by benchmarks/roofline_report.py.
+
+This is a fake-device CPU tool: it compiles for 256/512 host-platform
+devices and never runs on a chip. It sets ``XLA_FLAGS`` on import only
+where the caller has not put a device count there already.
 """
+
+import os
+# NOTE: while-loop LICM is disabled because XLA:CPU shadows every bf16 dot
+# operand with an f32 convert; LICM hoists those converts out of the scan
+# loops, materializing f32 copies of whole [L,B,S,D] remat stacks. TPU has
+# native bf16 MXU input, so the hoisted copies don't exist there — disabling
+# the pass makes the CPU memory analysis TPU-faithful.
+if "--xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count=512 "
+        "--xla_disable_hlo_passes=while-loop-invariant-code-motion"
+    )
 
 import argparse
 import dataclasses

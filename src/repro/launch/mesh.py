@@ -8,8 +8,6 @@ from __future__ import annotations
 
 import jax
 
-from repro.dist import compat  # noqa: F401  (AxisType/make_mesh shims)
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips/pod (TPU v5e pod slice); 2 pods = 512 chips."""

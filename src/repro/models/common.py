@@ -16,39 +16,6 @@ import jax.numpy as jnp
 Params = Dict[str, Any]
 
 
-def _native_barrier_differentiates() -> bool:
-    try:
-        jax.eval_shape(
-            lambda x: jax.jvp(jax.lax.optimization_barrier, (x,), (x,)),
-            jax.ShapeDtypeStruct((), jnp.float32),
-        )
-        return True
-    except NotImplementedError:
-        return False
-
-
-if _native_barrier_differentiates():
-    # modern jaxlib: the primitive has full AD rules (incl. forward mode)
-    optimization_barrier = jax.lax.optimization_barrier
-else:
-    # jaxlib < 0.4.38 defines no AD rule for the barrier primitive; it is
-    # semantically the identity, so the VJP barriers the cotangent instead
-    # — which also keeps the anti-LICM effect in the *backward* scan, where
-    # the hoisted-upcast problem the barrier exists for shows up
-    # symmetrically. (custom_vjp costs forward-mode AD, hence the gate.)
-    @jax.custom_vjp
-    def optimization_barrier(x: jax.Array) -> jax.Array:
-        return jax.lax.optimization_barrier(x)
-
-    def _ob_fwd(x):
-        return jax.lax.optimization_barrier(x), None
-
-    def _ob_bwd(_, g):
-        return (jax.lax.optimization_barrier(g),)
-
-    optimization_barrier.defvjp(_ob_fwd, _ob_bwd)
-
-
 def dense_init(key, d_in: int, d_out: int, dtype, scale: Optional[float] = None):
     scale = scale if scale is not None else 1.0 / math.sqrt(d_in)
     return (jax.random.normal(key, (d_in, d_out)) * scale).astype(dtype)

@@ -130,7 +130,6 @@ def pna_layer_fused(p, x, src, dst, emask, n, aggregators, scalers, delta):
     mesh, daxes, n_data = _fused_mesh()
     if mesh is None or n_data == 1 or src.shape[0] % n_data != 0:
         return pna_layer(p, x, src, dst, emask, n, aggregators, scalers, delta)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     d = gops._dspec(daxes)
@@ -180,12 +179,12 @@ def pna_layer_fused(p, x, src, dst, emask, n, aggregators, scalers, delta):
     if "min" in aggregators:
         keys.append("min")
     keys = sorted(keys)
-    res = shard_map(
+    res = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(None, None), P(None, None), P(d), P(d), P(d)),
         out_specs=tuple(P(d, None) for _ in keys),
-        check_rep=False,
+        check_vma=False,
     )(x, p["w_pre"], src, dst, emask)
     r = dict(zip(keys, res))
     cnt = jnp.maximum(r["cnt"][:, :1], 1.0)
@@ -222,7 +221,6 @@ def mpnn_layer_fused(p, x, e_feat, src, dst, emask, n):
     mesh, daxes, n_data = _fused_mesh()
     if mesh is None or n_data == 1 or src.shape[0] % n_data != 0:
         return mpnn_layer(p, x, e_feat, src, dst, emask, n)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     d = gops._dspec(daxes)
@@ -241,7 +239,7 @@ def mpnn_layer_fused(p, x, e_feat, src, dst, emask, n):
         )
         return e_new, agg
 
-    e_new, agg = shard_map(
+    e_new, agg = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -249,7 +247,7 @@ def mpnn_layer_fused(p, x, e_feat, src, dst, emask, n):
             P(d), P(d), P(d),
         ),
         out_specs=(P(d, None), P(d, None)),
-        check_rep=False,
+        check_vma=False,
     )(x, e_feat, p["edge_w1"], p["edge_w2"], src, dst, emask)
     x_new = (
         jax.nn.silu(jnp.concatenate([x, agg], axis=-1) @ p["node_w1"])

@@ -102,7 +102,7 @@ def forward(params, batch, cfg: GNNConfig):
 
         def gc_body(carry, lp):
             h, e = carry
-            h = common.optimization_barrier(h)
+            h = jax.lax.optimization_barrier(h)
             h, e = L.mpnn_layer_fused(lp, h, e, src, dst, emask, n)
             return (_c(h), _c(e)), None
 
@@ -125,7 +125,7 @@ def forward(params, batch, cfg: GNNConfig):
         h = maybe_ckpt(pna_apply)(cparams["layer0"], x)
         if cparams.get("layers") is not None:
             def pna_body(h, lp):
-                h = common.optimization_barrier(h)
+                h = jax.lax.optimization_barrier(h)
                 return maybe_ckpt(pna_apply)(lp, h), None
 
             h, _ = jax.lax.scan(pna_body, h, cparams["layers"])

@@ -133,7 +133,7 @@ def forward(params, tokens: jax.Array, cfg: TransformerConfig):
         # barrier: stops XLA LICM from hoisting the bf16→f32 upcast of the
         # carry out of the reverse loop (which would materialize an f32 copy
         # of the whole [L, B, S, D] remat stack — 2× activation memory)
-        x = common.optimization_barrier(x)
+        x = jax.lax.optimization_barrier(x)
         a, _, _ = _attn_block(lp, common.rms_norm(x, lp["ln1"]), pos, pos, cfg)
         x = constrain(x + a, (BATCH, None, None))
         f, aux_l = _ffn_block(lp, common.rms_norm(x, lp["ln2"]), cfg)
@@ -266,7 +266,7 @@ def prefill(params, tokens: jax.Array, cfg: TransformerConfig,
     x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.cdtype)
 
     def layer_fn(x, lp):
-        x = common.optimization_barrier(x)
+        x = jax.lax.optimization_barrier(x)
         a, nk, nv = _attn_block(lp, common.rms_norm(x, lp["ln1"]), pos, pos, cfg)
         x = constrain(x + a, (BATCH, None, None))
         f, _ = _ffn_block(lp, common.rms_norm(x, lp["ln2"]), cfg)

@@ -181,7 +181,6 @@ def moe_ffn_ep(x, params, mcfg: MoEConfig, mesh, daxes, n_data, n_model):
        then one psum over `model` — the EP combine all-reduce, the only
        wire traffic of the dispatch.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     t, d = x.shape
@@ -215,7 +214,7 @@ def moe_ffn_ep(x, params, mcfg: MoEConfig, mesh, daxes, n_data, n_model):
             aux,
         )
 
-    buf, eidx, gate, pos, keep, aux = shard_map(
+    buf, eidx, gate, pos, keep, aux = jax.shard_map(
         dispatch_local,
         mesh=mesh,
         in_specs=(P(dspec, None), P(None, None)),
@@ -227,7 +226,7 @@ def moe_ffn_ep(x, params, mcfg: MoEConfig, mesh, daxes, n_data, n_model):
             P(dspec),
             P(),
         ),
-        check_rep=False,
+        check_vma=False,
     )(x, params["router"])
 
     # --- expert compute (pjit; E model-sharded, C data-sharded) ----------
@@ -255,7 +254,7 @@ def moe_ffn_ep(x, params, mcfg: MoEConfig, mesh, daxes, n_data, n_model):
             y_partial = y_partial + vals * w[:, None]
         return jax.lax.psum(y_partial, "model")  # EP combine
 
-    y = shard_map(
+    y = jax.shard_map(
         combine_local,
         mesh=mesh,
         in_specs=(
@@ -266,7 +265,7 @@ def moe_ffn_ep(x, params, mcfg: MoEConfig, mesh, daxes, n_data, n_model):
             P(dspec),
         ),
         out_specs=P(dspec, None),
-        check_rep=False,
+        check_vma=False,
     )(expert_out, eidx, gate, pos, keep)
 
     if "shared" in params:

@@ -51,7 +51,6 @@ def make_compressed_dp_grad_fn(loss_fn, mesh, data_axis: str = "data"):
     where params are replicated, batch is sharded on ``data_axis``, and
     ``residuals`` is a params-shaped f32 pytree (init zeros).
     """
-    from jax.experimental.shard_map import shard_map
 
     def local(params, batch, residuals):
         loss, g = jax.value_and_grad(loss_fn)(params, batch)
@@ -80,10 +79,10 @@ def make_compressed_dp_grad_fn(loss_fn, mesh, data_axis: str = "data"):
         return loss, grads, new_res
 
     batch_spec = P(data_axis)
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), batch_spec, P()),
         out_specs=(P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )

@@ -68,7 +68,9 @@ class BSPResult:
 
 class _StagedStep:
     """One Palgol step: its :class:`StepPlan` compiled to per-op superstep
-    callables ``(fields, mailbox) -> (fields, mailbox)``; ``ns`` prefixes
+    callables ``(fields, mailbox, graph) -> (fields, mailbox)``; the graph
+    is an argument of every callable, never captured, so no graph array is
+    embedded in a dispatch's HLO as a constant. ``ns`` prefixes
     this step's mailbox keys so supersteps from different steps can share
     the program-level mailbox of the fused plan.
 
@@ -86,14 +88,14 @@ class _StagedStep:
     def __init__(
         self,
         step: ast.Step,
-        graph,
+        n_vertices: int,
         schedule: str,
         byte_costs: Optional[ByteCostModel] = None,
         plan: Optional[StepPlan] = None,
         ns: str = "",
     ):
         self.step = step
-        self.graph = graph
+        self.n = n_vertices
         self.plan = (
             plan
             if plan is not None
@@ -116,7 +118,7 @@ class _StagedStep:
 
     # -- read supersteps -----------------------------------------------------
     def read_stage_fns(self):
-        """List of jitted ``(fields, mailbox) -> mailbox`` functions; one
+        """List of jitted ``(fields, mailbox, graph) -> mailbox`` functions; one
         per ReadRound op of the plan (the accounting-mirror API)."""
         return [
             jax.jit(self._stage_fn(op))
@@ -130,8 +132,8 @@ class _StagedStep:
         (colliding requesters overwrite: no combining, as manual code);
         ``combine="min"`` is Pregel message combining (one deterministic
         slot per distinct owner). ``n_vertices`` is the empty sentinel."""
-        ids = jnp.arange(self.graph.n_vertices, dtype=jnp.int32)
-        reqbuf = jnp.full_like(ids, self.graph.n_vertices)
+        ids = jnp.arange(self.n, dtype=jnp.int32)
+        reqbuf = jnp.full_like(ids, self.n)
         if combine == "set":
             return reqbuf.at[owner].set(ids, mode="drop")
         return reqbuf.at[owner].min(ids, mode="drop")
@@ -139,7 +141,7 @@ class _StagedStep:
     def _stage_fn(self, op: ReadRound):
         if op.kind == "request":
 
-            def request(fields, mailbox, _op=op):
+            def request(fields, mailbox, graph, _op=op):
                 # requester u pushes its id to the owner vertex (real
                 # scatter: the message traffic manual Pregel code pays)
                 out = dict(mailbox)
@@ -154,7 +156,7 @@ class _StagedStep:
 
         if op.kind == "push_request":
 
-            def push_request(fields, mailbox, _op=op):
+            def push_request(fields, mailbox, graph, _op=op):
                 # address-propagation round: requester ids move one hop
                 # along the chain, message-combined per owner (one slot
                 # per distinct owner — the scatter-min IS the combiner)
@@ -170,7 +172,7 @@ class _StagedStep:
 
             return push_request
 
-        def stage(fields, mailbox, _op=op):
+        def stage(fields, mailbox, graph, _op=op):
             # "pull": one gather-DAG round; "reply": the owner returns its
             # value to the requester; "push_reply": one combined reply per
             # distinct owner, fanned out to its requesters (the gather),
@@ -191,7 +193,7 @@ class _StagedStep:
                         pre, _op.combiner or "min"
                     )
                     val = val + (
-                        gops.gather(reqbuf, pre) // (self.graph.n_vertices + 2)
+                        gops.gather(reqbuf, pre) // (self.n + 2)
                     ).astype(val.dtype)
                 out[self._key(ce.pattern)] = val
                 out.pop(self._key(ce.pattern) + ":req", None)
@@ -203,7 +205,7 @@ class _StagedStep:
                 for k in [k for k in out if k.startswith(prefix)]:
                     out.pop(k)
             for direction, npat in _op.nbr_sends:
-                nbr, _, _, _ = self.graph.edges(direction)
+                nbr, _, _, _ = graph.edges(direction)
                 val = self._lookup(fields, out, npat)
                 out[self._nkey(direction, npat)] = gops.gather(val, nbr)
             return out
@@ -219,23 +221,23 @@ class _StagedStep:
 
     def _lookup(self, fields, mailbox, pattern):
         if len(pattern) == 0:
-            return jnp.arange(self.graph.n_vertices, dtype=jnp.int32)
+            return jnp.arange(self.n, dtype=jnp.int32)
         if len(pattern) == 1:
             if pattern[0] == "Id":
-                return jnp.arange(self.graph.n_vertices, dtype=jnp.int32)
+                return jnp.arange(self.n, dtype=jnp.int32)
             return fields[pattern[0]]
         return mailbox[self._key(pattern)]
 
     # -- per-op superstep callables -------------------------------------------
     def op_fn(self, op):
-        """``(fields, mailbox) -> (fields, mailbox)`` for one plan op — the
+        """``(fields, mailbox, graph) -> (fields, mailbox)`` for one plan op — the
         building block the per-superstep dispatcher composes (a fused
         superstep is several of these sequenced inside one jit)."""
         if isinstance(op, ReadRound):
             stage = self._stage_fn(op)
 
-            def read(fields, mailbox):
-                return fields, stage(fields, mailbox)
+            def read(fields, mailbox, graph):
+                return fields, stage(fields, mailbox, graph)
 
             return read
         if isinstance(op, RemoteUpdate):
@@ -247,7 +249,7 @@ class _StagedStep:
         materialized = self.plan.materialized
         pending_key = self.ns + "pending"
 
-        def main(fields, mailbox):
+        def main(fields, mailbox, graph):
             chain_values = {
                 p: mailbox[self._key(p)]
                 for p in materialized
@@ -265,7 +267,7 @@ class _StagedStep:
                 k: v for k, v in mailbox.items()
                 if not k.startswith(self.ns)
             }
-            ex = StepExecutor(self.step, self.graph, plan=self.plan)
+            ex = StepExecutor(self.step, graph, plan=self.plan)
             if has_ru:
                 new, pending = ex(
                     fields, chain_values, split_remote=True,
@@ -282,10 +284,10 @@ class _StagedStep:
     def _update_fn(self, ru: RemoteUpdate):
         pending_key = self.ns + "pending"
 
-        def update(fields, mailbox):
+        def update(fields, mailbox, graph):
             out = dict(mailbox)
             payload = out.pop(pending_key)
-            ex = StepExecutor(self.step, self.graph, plan=self.plan)
+            ex = StepExecutor(self.step, graph, plan=self.plan)
             msgs = [
                 _RemoteMsg(f, op, idx, val, mask)
                 for (f, op), (idx, val, mask) in zip(ru.writes, payload)
@@ -293,6 +295,37 @@ class _StagedStep:
             return ex.apply_remote(fields, msgs), out
 
         return update
+
+def _make_staged_superstep_fn(
+    ss: plan_mod.Superstep, n_vertices: int, staged: Dict[int, _StagedStep]
+):
+    """jit of ONE fused superstep's parts in order:
+    ``(fields, mailbox, graph) -> (fields, mailbox)``. ``staged`` caches
+    one :class:`_StagedStep` per program step across supersteps."""
+    part_fns = []
+    for ref in ss.parts:
+        op = ref.op
+        if isinstance(op, plan_mod.IterInit):
+            continue
+        if isinstance(op, plan_mod.StopOp):
+            part_fns.append(
+                lambda f, m, g, _s=op.stop: (make_stop_fn(_s, g)(f), m)
+            )
+            continue
+        if ref.sidx not in staged:
+            staged[ref.sidx] = _StagedStep(
+                ref.plan.step, n_vertices, ref.plan.schedule,
+                plan=ref.plan, ns=f"s{ref.sidx}:",
+            )
+        part_fns.append(staged[ref.sidx].op_fn(op))
+
+    def ss_fn(flds, mailbox, graph):
+        for fn in part_fns:
+            flds, mailbox = fn(flds, mailbox, graph)
+        return flds, mailbox
+
+    return jax.jit(ss_fn)
+
 
 def read_superstep_count(step: ast.Step, schedule: str) -> int:
     """Number of remote-reading supersteps a step costs under ``schedule``
@@ -432,37 +465,12 @@ def run_bsp(
     ss_fns: Dict[int, object] = {}
     mailbox_box = [{}]
 
-    def staged_for(ref: plan_mod.OpRef) -> _StagedStep:
-        if ref.sidx not in staged:
-            staged[ref.sidx] = _StagedStep(
-                ref.plan.step, graph, schedule,
-                plan=ref.plan, ns=f"s{ref.sidx}:",
-            )
-        return staged[ref.sidx]
-
-    def build_ss_fn(ss: plan_mod.Superstep):
-        part_fns = []
-        for ref in ss.parts:
-            op = ref.op
-            if isinstance(op, plan_mod.IterInit):
-                continue
-            if isinstance(op, plan_mod.StopOp):
-                stop = make_stop_fn(op.stop, graph)
-                part_fns.append(lambda f, m, _s=stop: (_s(f), m))
-            else:
-                part_fns.append(staged_for(ref).op_fn(op))
-
-        def ss_fn(flds, mailbox):
-            for fn in part_fns:
-                flds, mailbox = fn(flds, mailbox)
-            return flds, mailbox
-
-        return jax.jit(ss_fn)
-
     def exec_superstep(ss: plan_mod.Superstep, flds):
         if id(ss) not in ss_fns:
-            ss_fns[id(ss)] = build_ss_fn(ss)
-        flds, mailbox_box[0] = ss_fns[id(ss)](flds, mailbox_box[0])
+            ss_fns[id(ss)] = _make_staged_superstep_fn(
+                ss, graph.n_vertices, staged
+            )
+        flds, mailbox_box[0] = ss_fns[id(ss)](flds, mailbox_box[0], graph)
         return flds
 
     fields = {k: jnp.asarray(v) for k, v in fields.items()}

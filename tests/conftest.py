@@ -1,8 +1,7 @@
-"""Suite-wide config: CPU pinning, deterministic seeds, dep fallbacks.
+"""Suite-wide config: CPU pinning and deterministic seeds.
 
 Loaded before any test module imports, so environment pins land before
-jax initializes a backend and the hypothesis fallback is in place before
-``from hypothesis import given`` runs.
+jax initializes a backend.
 """
 
 import os
@@ -18,23 +17,8 @@ SRC = Path(__file__).resolve().parent.parent / "src"
 if str(SRC) not in sys.path:  # belt-and-braces next to pyproject pythonpath
     sys.path.insert(0, str(SRC))
 
-# -- hypothesis fallback ----------------------------------------------------
-# Hermetic images may lack hypothesis; substitute the deterministic stub so
-# the property tests still run as seeded random testing (same test code).
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    from repro.testing import hypothesis_stub
-
-    sys.modules["hypothesis"] = hypothesis_stub
-    sys.modules["hypothesis.strategies"] = hypothesis_stub.strategies
-
 import numpy as np
 import pytest
-
-# Mesh-API shims (jax.sharding.AxisType / make_mesh(axis_types=...)) for
-# jaxlib < 0.4.38 — tests build meshes directly, so install suite-wide.
-from repro.dist import compat  # noqa: E402, F401
 
 #: the one seed every fixture derives from — change here, change everywhere
 SUITE_SEED = 170309542  # arXiv 1703.09542, digits only

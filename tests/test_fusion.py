@@ -7,7 +7,7 @@ Four layers:
   read-after-write boundary, iteration fusion duplicates the body's
   leading ReadRound into the preceding superstep and merges it into the
   body's tail;
-* **former-STM equivalence** (hypothesis-stub compatible property): on
+* **former-STM equivalence** (hypothesis property): on
   randomized chain programs the fused plan's superstep totals equal the
   pre-refactor ``build_stm(..., optimize=True)`` accounting — the
   unconditional-merge + iteration-fusion logic this PR deleted from

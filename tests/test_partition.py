@@ -205,7 +205,7 @@ class TestSuperstepAccounting:
         for step in iter_steps(cp.prog):
             if not isinstance(step, past.Step):
                 continue
-            staged = _StagedStep(step, g, schedule)
+            staged = _StagedStep(step, g.n_vertices, schedule)
             assert read_superstep_count(step, schedule) == len(
                 staged.read_stage_fns()
             ), (name, schedule)

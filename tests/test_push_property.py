@@ -1,6 +1,5 @@
 """Property tests for the executable push schedule + the byte-aware
-``auto`` selector (hypothesis-stub compatible: on hermetic images the
-``repro.testing.hypothesis_stub`` shim runs these as seeded random tests).
+``auto`` selector.
 
 Invariants encoded:
 

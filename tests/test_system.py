@@ -20,7 +20,7 @@ def test_end_to_end_sssp_pipeline():
     assert finite.sum() >= 1
     assert (D[finite] >= 0).all()
     # the compiled program is a single jittable XLA computation
-    lowered = jax.jit(cp.fn).lower(cp.init_fields())
+    lowered = jax.jit(cp.fn).lower(cp.init_fields(), g)
     text = lowered.as_text()
     assert "while" in text  # the fixed-point iteration lowered to lax.while
 
@@ -41,5 +41,5 @@ def test_whole_program_is_one_xla_module():
     taken to its logical conclusion on a shared-address-space machine)."""
     g = G.erdos_renyi(64, 4.0, seed=2)
     cp = compile_program(alg.WCC, g)
-    compiled = jax.jit(cp.fn).lower(cp.init_fields()).compile()
+    compiled = jax.jit(cp.fn).lower(cp.init_fields(), g).compile()
     assert compiled.cost_analysis() is not None

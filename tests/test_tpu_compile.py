@@ -1,0 +1,201 @@
+"""Compile the main-path programs and kernels for a described TPU v5e.
+
+Nothing here runs on a chip. Every test compiles ahead of time, from
+shapes only, for a v5e topology described by
+``jax.experimental.topologies``, so what the TPU compiler refuses (block
+tiling, memory, partitioning) shows on a machine without one. The topology
+is described inside a fixture, never while a module is imported: only one
+process at a time may load the TPU library.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import algorithms as alg
+from repro.core import compile_program
+from repro.core import plan as plan_mod
+from repro.graph.partition.executor import _make_superstep_fn
+from repro.graph.partition.partitioner import HaloSpec, PartitionedGraph
+from repro.graph.structure import Graph, from_edge_list
+from repro.kernels.embedding_bag import embedding_bag_pallas
+from repro.kernels.gather_rows import gather_rows_pallas
+from repro.kernels.segment_reduce.kernel import segment_sum_ell_kernel
+
+#: Graph500 scale 20, edgefactor 16: directed, and symmetrised (twice that)
+SCALE = 20
+N = 1 << SCALE
+E_DIR = 16 * N
+E_SYM = 2 * E_DIR
+V5E_HBM_BYTES = 16 * 1024**3
+
+_BLOCK_RULE = (
+    "The Pallas TPU lowering currently requires that the last two dimensions "
+    "of your block shape are divisible by 8 and 128 respectively, or be "
+    "equal to the respective dimensions of the overall array"
+)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the cache but cannot be
+    # read back without one: keep the cache out of these compiles
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    return Mesh(np.array(topo.devices[:4]), ("shard",))
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _graph_shapes(n_edges, sharding) -> Graph:
+    i32, f32, b = jnp.int32, jnp.float32, jnp.bool_
+
+    def arr(dtype):
+        return _sds((n_edges,), dtype, sharding)
+
+    return Graph(
+        src=arr(i32), dst=arr(i32), weight=arr(f32), edge_mask=arr(b),
+        t_src=arr(i32), t_dst=arr(i32), t_weight=arr(f32), t_mask=arr(b),
+        n_vertices=N, n_edges=n_edges,
+    )
+
+
+def _program(name):
+    # compile_program needs a concrete graph only for its vertex count
+    tiny = from_edge_list(np.array([0]), np.array([1]), N)
+    return compile_program(alg.ALL[name], tiny)
+
+
+@pytest.mark.parametrize(
+    "name,n_edges", [("sv", E_SYM), ("wcc", E_SYM), ("sssp", E_DIR)]
+)
+def test_dense_program_compiles_with_graph_argument(one_chip, name, n_edges):
+    cp = _program(name)
+    fields = {
+        k: _sds(v.shape, v.dtype, one_chip) for k, v in cp.field_struct.items()
+    }
+    compiled = (
+        jax.jit(cp.fn).lower(fields, _graph_shapes(n_edges, one_chip)).compile()
+    )
+    mem = compiled.memory_analysis()
+    # the edge arrays arrive as arguments (src, dst, mask at least)
+    assert mem.argument_size_in_bytes >= 9 * n_edges
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+def _partitioned_graph_shapes(mesh, n_shards) -> PartitionedGraph:
+    split = NamedSharding(mesh, P("shard"))
+    whole = NamedSharding(mesh, P())
+    # contiguous ranges on an id-ordered Kronecker graph: the hub shard is
+    # short, so v_max runs to about half the vertices
+    v_max, e_max = N // 2, E_SYM * 13 // (10 * n_shards)
+    ghosts, pair = v_max, v_max // 2
+    i32, f32, b = jnp.int32, jnp.float32, jnp.bool_
+
+    def blk(dtype, *shape):
+        return _sds((n_shards,) + shape, dtype, split)
+
+    def halo():
+        return HaloSpec(
+            ghost_ids=blk(i32, ghosts),
+            send_local=blk(i32, n_shards, pair),
+            recv_pos=blk(i32, n_shards, pair),
+            n_ghost=ghosts, pair_cap=pair,
+        )
+
+    return PartitionedGraph(
+        starts=_sds((n_shards + 1,), i32, whole), vmask=blk(b, v_max),
+        src_g=blk(i32, e_max), src_h=blk(i32, e_max), dst_l=blk(i32, e_max),
+        w=blk(f32, e_max), emask=blk(b, e_max),
+        t_dst_g=blk(i32, e_max), t_dst_h=blk(i32, e_max),
+        t_src_l=blk(i32, e_max), t_w=blk(f32, e_max), t_emask=blk(b, e_max),
+        halo_in=halo(), halo_out=halo(),
+        n_vertices=N, n_edges=E_SYM, n_shards=n_shards, v_max=v_max,
+        e_max=e_max,
+    )
+
+
+def test_partitioned_sv_first_superstep_compiles(mesh4):
+    cp = _program("sv")
+    pg = _partitioned_graph_shapes(mesh4, 4)
+    split = NamedSharding(mesh4, P("shard"))
+    fields = {
+        k: _sds((4, pg.v_max) + v.shape[1:], v.dtype, split)
+        for k, v in cp.field_struct.items()
+    }
+    pp = plan_mod.fuse(plan_mod.lower_program(cp.prog, schedule="pull"))
+    first = next(
+        it for it in pp.items if isinstance(it, plan_mod.Superstep)
+    )
+    compiled = _make_superstep_fn(first, pg, mesh4).lower(
+        fields, {}, pg
+    ).compile()
+    assert "all-to-all" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("width", [1, 128])
+def test_segment_sum_ell_kernel_compiles(one_chip, width):
+    n_edges, nb, budget = 1 << 24, 256, 4096
+    kernel = functools.partial(
+        segment_sum_ell_kernel, n_blocks=n_edges // budget, nb=nb,
+        budget=budget,
+    )
+    compiled = jax.jit(kernel).lower(
+        _sds((n_edges,), jnp.int32, one_chip),
+        _sds((n_edges, width), jnp.float32, one_chip),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.xfail(
+    strict=True, raises=ValueError,
+    reason=f"gather_rows: its (1, d) row blocks are refused: {_BLOCK_RULE}",
+)
+def test_gather_rows_compiles(one_chip):
+    gather_rows_pallas.lower(
+        _sds((N, 128), jnp.float32, one_chip),
+        _sds((4096,), jnp.int32, one_chip),
+    ).compile()
+
+
+@pytest.mark.xfail(
+    strict=True, raises=ValueError,
+    reason=f"embedding_bag: its (1, hot) index blocks are refused: {_BLOCK_RULE}",
+)
+def test_embedding_bag_compiles(one_chip):
+    embedding_bag_pallas.lower(
+        _sds((N, 128), jnp.float32, one_chip),
+        _sds((256, 8), jnp.int32, one_chip),
+    ).compile()
