@@ -296,6 +296,7 @@ class _StagedStep:
 
         return update
 
+
 def _make_staged_superstep_fn(
     ss: plan_mod.Superstep, n_vertices: int, staged: Dict[int, _StagedStep]
 ):
