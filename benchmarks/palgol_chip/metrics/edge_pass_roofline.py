@@ -1,0 +1,21 @@
+"""The traced job's essential edge-pass bytes (``edge_bytes.job_bytes``)
+over what the chip's peak HBM bandwidth moves in the job's device busy
+time, in %."""
+
+import edge_bytes
+
+
+def read(record):
+    trace = record["trace"]
+    ran = [j for j in record["jobs"] if "trips" in j]
+    if trace is None or not ran or trace["busy_s"] <= 0:
+        return None
+    j = ran[0]
+    moved = edge_bytes.job_bytes(
+        record["program"], j["trips"], record["n_vertices"],
+        record["live_edges"], j["itemsize"],
+    )
+    if not moved:
+        return None
+    peak = record["peaks"]["hbm_bytes_per_s"]
+    return 100.0 * moved / (peak * trace["busy_s"])
