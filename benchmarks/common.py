@@ -1,23 +1,6 @@
-"""Benchmark timing utilities."""
+"""The CSV row format of ``benchmarks/run.py``."""
 
 from __future__ import annotations
-
-import time
-
-import jax
-
-
-def time_fn(fn, *args, warmup: int = 2, iters: int = 5) -> float:
-    """Median wall time per call in microseconds (blocking on results)."""
-    for _ in range(warmup):
-        jax.block_until_ready(fn(*args))
-    times = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(*args))
-        times.append(time.perf_counter() - t0)
-    times.sort()
-    return times[len(times) // 2] * 1e6
 
 
 def row(name: str, us: float, derived: str = "") -> str:

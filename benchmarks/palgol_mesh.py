@@ -101,8 +101,7 @@ def run_cell(algo: str, mode: str, n: int, e: int, mesh):
     gshard = shd.batch_shardings("gnn", ag, mesh)
 
     def step(flds, graph):
-        out, _ = cp_body.fn(flds, graph=graph)
-        return out
+        return cp_body.fn(flds, graph=graph)[0]
 
     with mesh:
         lowered = jax.jit(

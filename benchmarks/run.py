@@ -1,13 +1,12 @@
-"""Benchmark harness — one section per paper table + kernel/roofline rows.
+"""Count reports: one section per paper table + roofline rows.
 
     PYTHONPATH=src python -m benchmarks.run [--scale 10]
 
-Prints ``name,us_per_call,derived`` CSV:
-  table4/*   — execution time, Palgol-compiled vs manual-style (paper Tab.4)
+Prints ``name,us_per_call,derived`` CSV (the counts are in ``derived``):
   table5/*   — superstep counts under the three compilers (paper Tab.5)
-  kernels/*  — substrate hot-path timings (XLA fallbacks the Pallas kernels
-               replace; kernels themselves validate in interpret mode)
   roofline/* — per-cell dry-run roofline terms (from experiments/dryrun)
+
+Times are measured on the chip, by ``benchmarks/palgol_chip/run.py``.
 """
 
 import argparse
@@ -16,8 +15,8 @@ import argparse
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=int, default=10,
-                    help="log2 graph size for table4/5 (default 2^10)")
-    ap.add_argument("--sections", default="table5,table4,kernels,roofline")
+                    help="log2 graph size for table5 (default 2^10)")
+    ap.add_argument("--sections", default="table5,roofline")
     args = ap.parse_args()
     sections = set(args.sections.split(","))
 
@@ -31,16 +30,6 @@ def main() -> None:
         from benchmarks import table5_supersteps
 
         rows += table5_supersteps.run(args.scale)
-        _flush(rows)
-    if "table4" in sections:
-        from benchmarks import table4_exec_time
-
-        rows += table4_exec_time.run(args.scale)
-        _flush(rows)
-    if "kernels" in sections:
-        from benchmarks import bench_kernels
-
-        rows += bench_kernels.run()
         _flush(rows)
     if "roofline" in sections:
         from benchmarks import roofline_report

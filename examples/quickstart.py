@@ -31,6 +31,8 @@ def main():
     print(f"\nreachable vertices: {finite.sum()}; "
           f"max distance: {D[finite].max():.3f}; iterations: {trips[0]}")
 
+    active_sets = counts.pop("active_sets")
+    print(f"vertices changed per trip: {active_sets[0]}")
     print("\nsuperstep accounting (paper Table 5 analogue):")
     for k, v in counts.items():
         print(f"  {k:12} {v}")

@@ -18,10 +18,32 @@ The emitted functions contain no data-dependent Python control flow, so a
 whole program (including fixed-point iterations as ``lax.while_loop``) traces
 into a single XLA computation — one compiled module per Palgol program, with
 collectives inserted by GSPMD when fields are sharded.
+
+Device work is named after the Palgol program (``jax.named_scope``, which
+lands in the HLO ``op_name`` metadata and so in the device trace; it
+changes no executable). Every executor opens ``palgol`` and one
+``L<iter_index>`` per enclosing loop (:func:`plan_scope`), each plan part
+opens ``s<sidx>`` (the step's ordinal in program order), and the step's
+work falls under one of the leaves:
+
+* ``chain``: a ReadRound's chain gathers, the naive request scatter, and
+  reads by computed address;
+* ``nbr``: neighbour access: per-edge gathers of neighbour values, edge
+  masks, segment reductions over edges;
+* ``remote``: building remote-write messages and applying them;
+* ``local``: the rest of the main computation;
+* ``stop``: a StopStep;
+
+and a loop's termination test and frontier count under ``fixpoint``
+(``palgol/L<i>/fixpoint``). A leaf opened inside another (a neighbour
+reduction inside a local ``let``) nests, so the innermost leaf names the
+work. The dense compiler's loops are ``lax.while_loop`` s, so JAX puts
+``while/body`` between ``L<i>`` and the parts in its names.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Dict, List, Optional, Tuple
 
@@ -57,6 +79,26 @@ _OP_APPLY = {
     "||=": jnp.logical_or,
     "&&=": jnp.logical_and,
 }
+
+def plan_scope(loops=()):
+    """``jax.named_scope`` of work inside the loops ``loops`` (iter
+    indices, outermost first): ``palgol/L<i>/...``."""
+    return jax.named_scope("/".join(["palgol"] + [f"L{i}" for i in loops]))
+
+
+def frontier_count(before, after, fix_fields, vertex_ndim: int = 1):
+    """Vertices whose fix fields changed (the fixed-point frontier), as a
+    device int32 scalar; a loop has converged when it is 0.
+    ``vertex_ndim`` is the number of leading per-vertex dims (1 dense, 2
+    for ``[shard, row]``-blocked partitioned state)."""
+    changed = None
+    for f in fix_fields:
+        d = after[f] != before[f]
+        if d.ndim > vertex_ndim:
+            d = d.reshape(d.shape[:vertex_ndim] + (-1,)).any(axis=-1)
+        changed = d if changed is None else jnp.logical_or(changed, d)
+    return jnp.sum(changed, dtype=jnp.int32)
+
 
 _REDUCE_TO_COMBINER = {
     "minimum": "min",
@@ -146,6 +188,8 @@ class StepExecutor:
         self.plan = plan
         self.info = plan.info
         self.pull = PullSolver()
+        self._leaf: Optional[str] = None
+        self._active: Optional[jax.Array] = None
 
     # -- public -------------------------------------------------------------
     def __call__(
@@ -173,12 +217,12 @@ class StepExecutor:
         self.expr_cache: Dict[Tuple[int, ast.Expr], jax.Array] = {}
         self.pending: List[_RemoteMsg] = []
         self._naive_req: Dict[tuple, jax.Array] = {}
-        self.active = self._active_mask(fields)
+        self._active = None
         for op in self.plan.ops:
             if isinstance(op, ReadRound):
                 self._exec_read_round(op)
             elif isinstance(op, MainCompute):
-                self._exec_stmts(self.step.body, mask=None, ectx=None)
+                self._main_compute()
             elif not split_remote:  # RemoteUpdate
                 self._apply_remote()
         if split_remote:
@@ -190,7 +234,7 @@ class StepExecutor:
         self.old = dict(fields)
         self.new = dict(fields)
         self.pending = pending
-        self.active = self._active_mask(fields)
+        self._active = None
         self._apply_remote()
         return self.new
 
@@ -216,12 +260,12 @@ class StepExecutor:
         self.expr_cache = {}
         self.pending = list(state.pending)
         self._naive_req = dict(state.naive_req)
-        self.active = self._active_mask(fields)
+        self._active = None
         for op in ops:
             if isinstance(op, ReadRound):
                 self._exec_read_round(op)
             elif isinstance(op, MainCompute):
-                self._exec_stmts(self.step.body, mask=None, ectx=None)
+                self._main_compute()
             else:  # RemoteUpdate
                 self._apply_remote()
                 self.pending = []
@@ -237,6 +281,33 @@ class StepExecutor:
         return self.new, out_state
 
     # -- helpers ------------------------------------------------------------
+    @contextlib.contextmanager
+    def _in(self, leaf: str):
+        """Name the device work of the ``with`` body by ``leaf`` (a leaf
+        of the module doc); nothing is added where ``leaf`` is already the
+        innermost leaf."""
+        if self._leaf == leaf:
+            yield
+            return
+        outer, self._leaf = self._leaf, leaf
+        try:
+            with jax.named_scope(leaf):
+                yield
+        finally:
+            self._leaf = outer
+
+    @property
+    def active(self) -> jax.Array:
+        """The input rows that are not halted; made where first needed, so
+        that its ops are named after the work that needs them."""
+        if self._active is None:
+            self._active = self._active_mask(self.old)
+        return self._active
+
+    def _main_compute(self):
+        with self._in("local"):
+            self._exec_stmts(self.step.body, mask=None, ectx=None)
+
     def _active_mask(self, fields) -> jax.Array:
         active = ~fields.get(HALTED, jnp.zeros((self.nrows,), jnp.bool_))
         if self.comm is not None:  # padding rows of a shard are never active
@@ -292,7 +363,8 @@ class StepExecutor:
             plan = self.pull.solve(pattern)
             pre = self._chain_value(plan.prefix.pattern)
             suf = self._chain_value(plan.suffix.pattern)
-            val = self._gather_rows(suf, pre)
+            with self._in("chain"):
+                val = self._gather_rows(suf, pre)
         self.chain_cache[pattern] = val
         return val
 
@@ -303,6 +375,19 @@ class StepExecutor:
         Work whose result is already cached (seeded by a staged mailbox)
         is skipped — the op then only accounts for its superstep.
         """
+        with self._in("chain"):
+            self._read_chains(op)
+        with self._in("nbr"):
+            for direction, npat in op.nbr_sends:
+                if (direction, npat) in self.nbr_cache:
+                    continue
+                per_vertex = self._chain_value(npat)
+                ectx = self._edge_ctx(direction)
+                self.nbr_cache[(direction, npat)] = self._read_nbr(
+                    per_vertex, ectx
+                )
+
+    def _read_chains(self, op: ReadRound):
         if op.kind == "request":
             # naive hop, requester→owner address push. Under a partitioned
             # comm the paired reply's gather_global pays the request
@@ -342,12 +427,6 @@ class StepExecutor:
                 # prove it — the scatter survives into the lowering
                 val = val + (req // (self.n + 2)).astype(val.dtype)
             self.chain_cache[ce.pattern] = val
-        for direction, npat in op.nbr_sends:
-            if (direction, npat) in self.nbr_cache:
-                continue
-            per_vertex = self._chain_value(npat)
-            ectx = self._edge_ctx(direction)
-            self.nbr_cache[(direction, npat)] = self._read_nbr(per_vertex, ectx)
 
     # -- expression evaluation ----------------------------------------------
     def _eval(self, e: ast.Expr, ectx: Optional[_EdgeCtx]):
@@ -392,12 +471,14 @@ class StepExecutor:
                     if cached is not None:
                         return cached
                     per_vertex = self._chain_value(npat)
-                    return self._read_nbr(per_vertex, ectx)
+                    with self._in("nbr"):
+                        return self._read_nbr(per_vertex, ectx)
             # general read
             idx = self._eval(e.index, ectx)
-            return self._gather_rows(
-                self._field(e.field), jnp.asarray(idx, jnp.int32)
-            )
+            with self._in("chain"):
+                return self._gather_rows(
+                    self._field(e.field), jnp.asarray(idx, jnp.int32)
+                )
         if isinstance(e, ast.Cond):
             c = self._eval(e.cond, ectx)
             t = self._eval(e.then, ectx)
@@ -429,6 +510,10 @@ class StepExecutor:
         return rec(e)
 
     def _eval_reduce(self, e: ast.Reduce) -> jax.Array:
+        with self._in("nbr"):
+            return self._edge_reduce(e)
+
+    def _edge_reduce(self, e: ast.Reduce) -> jax.Array:
         ectx = self._edge_ctx(e.range.direction)
         mask = ectx.emask
         for f in e.filters:
@@ -485,11 +570,14 @@ class StepExecutor:
                     m_else = ~c if mask is None else jnp.logical_and(mask, ~c)
                     self._exec_stmts(s.other, m_else, ectx)
             elif isinstance(s, ast.ForEdges):
-                ec = self._edge_ctx(s.range.direction)
-                m = ec.emask
-                if mask is not None:  # lift vertex mask to edges
-                    m = jnp.logical_and(m, gops.gather(mask, ec.seg, fill=False))
-                self._exec_stmts(s.body, m, ec)
+                with self._in("nbr"):
+                    ec = self._edge_ctx(s.range.direction)
+                    m = ec.emask
+                    if mask is not None:  # lift vertex mask to edges
+                        m = jnp.logical_and(
+                            m, gops.gather(mask, ec.seg, fill=False)
+                        )
+                    self._exec_stmts(s.body, m, ec)
             elif isinstance(s, ast.LocalWrite):
                 self._local_write(s, mask, ectx)
             elif isinstance(s, ast.RemoteWrite):
@@ -534,6 +622,10 @@ class StepExecutor:
             self.new[s.field] = jnp.where(self.active, updated, cur)
 
     def _remote_write(self, s: ast.RemoteWrite, mask, ectx: Optional[_EdgeCtx]):
+        with self._in("remote"):
+            self._emit_remote(s, mask, ectx)
+
+    def _emit_remote(self, s: ast.RemoteWrite, mask, ectx: Optional[_EdgeCtx]):
         idx = jnp.asarray(self._eval(s.target, ectx), jnp.int32)
         val = jnp.asarray(self._eval(s.value, ectx))
         shape = ectx.seg.shape if ectx is not None else (self.nrows,)
@@ -553,6 +645,10 @@ class StepExecutor:
         self.pending.append(_RemoteMsg(s.field, s.op, idx, val, m))
 
     def _apply_remote(self):
+        with self._in("remote"):
+            self._apply_messages()
+
+    def _apply_messages(self):
         for msg in self.pending:
             if msg.field not in self.new:
                 raise CompileError(
@@ -701,20 +797,24 @@ def exec_plan_part(ref: OpRef, graph, comm, fields, mailbox):
     The shared per-op consumer of the program plan: the fused dense
     compiler folds these calls into its single trace (``comm=None``) and
     the partitioned executor runs them inside its per-superstep shard_map
-    body (``comm=ShardComm``). Returns ``(fields, mailbox)``.
+    body (``comm=ShardComm``). Returns ``(fields, mailbox)``. The part's
+    device work is named ``s<sidx>/<leaf>`` (module doc), inside the
+    caller's :func:`plan_scope`.
     """
     op = ref.op
     if isinstance(op, IterInit):
         return fields, mailbox
-    if isinstance(op, StopOp):
-        return make_stop_fn(op.stop, graph, comm=comm)(fields), mailbox
-    ns = f"s{ref.sidx}:"
-    plan = ref.plan
-    ru = next((o for o in plan.ops if isinstance(o, RemoteUpdate)), None)
-    state = _ns_import(ns, mailbox, ru.writes if ru is not None else ())
-    ex = StepExecutor(plan.step, graph, comm=comm, plan=plan)
-    fields, state = ex.run_ops(fields, [op], state)
-    return fields, _ns_export(ns, mailbox, op, state)
+    with jax.named_scope(f"s{ref.sidx}"):
+        if isinstance(op, StopOp):
+            with jax.named_scope("stop"):
+                return make_stop_fn(op.stop, graph, comm=comm)(fields), mailbox
+        ns = f"s{ref.sidx}:"
+        plan = ref.plan
+        ru = next((o for o in plan.ops if isinstance(o, RemoteUpdate)), None)
+        state = _ns_import(ns, mailbox, ru.writes if ru is not None else ())
+        ex = StepExecutor(plan.step, graph, comm=comm, plan=plan)
+        fields, state = ex.run_ops(fields, [op], state)
+        return fields, _ns_export(ns, mailbox, op, state)
 
 
 def make_stop_fn(stop: ast.StopStep, graph, comm=None):
@@ -730,7 +830,6 @@ def make_stop_fn(stop: ast.StopStep, graph, comm=None):
         ex.nbr_cache = {}
         ex.expr_cache = {}
         ex.pending = []
-        ex.active = ex._active_mask(fields)
         cond = jnp.asarray(ex._eval(stop.cond, None))
         if cond.ndim == 0:
             cond = jnp.broadcast_to(cond, (ex.nrows,))

@@ -1,12 +1,13 @@
 """Palgol program compilation: AST → executable JAX + STM cost models.
 
 ``compile_program`` produces a :class:`CompiledProgram` whose ``fn`` is a
-pure, jit-able ``(fields, graph) → (fields, trips)`` function: fixed-point iterations
-become ``lax.while_loop`` (termination via a global any-changed reduction —
-Pregel's OR aggregator), sequences compose, and the whole Palgol program
-traces into a single XLA computation. ``trips`` counts body executions per
-iteration node so the STM cost models can report superstep totals for the
-paper's Table-5 accounting.
+pure, jit-able ``(fields, graph) → (fields, trips, frontier)`` function:
+fixed-point iterations become ``lax.while_loop`` (termination when no
+vertex's fix fields changed — Pregel's OR aggregator, counted), sequences
+compose, and the whole Palgol program traces into a single XLA
+computation. ``trips`` counts body executions per iteration node so the
+STM cost models can report superstep totals for the paper's Table-5
+accounting; ``frontier`` holds how many vertices changed on each trip.
 """
 
 from __future__ import annotations
@@ -23,11 +24,23 @@ from repro.core import parser as palgol_parser
 from repro.core import plan as plan_mod
 from repro.core import stm as stm_mod
 from repro.core.analysis import CompileError, iter_steps
-from repro.core.codegen import HALTED, StepExecutor, exec_plan_part, make_stop_fn
+from repro.core.codegen import (
+    HALTED,
+    StepExecutor,
+    exec_plan_part,
+    frontier_count,
+    make_stop_fn,
+    plan_scope,
+)
 from repro.core.plan import ByteCostModel, SCHEDULES, lower_step
+from repro.trace import span
 
 # pre-order Iter list — the shared iteration-counter index order
 _iter_nodes = plan_mod.iter_nodes
+
+#: trips of one loop whose frontier ``fn`` keeps one by one; later trips
+#: are added into the last slot
+FRONTIER_TRIPS = 1024
 
 
 @dataclasses.dataclass
@@ -98,7 +111,8 @@ class CompiledProgram:
         return fields
 
     def fn(self, fields: Dict[str, jax.Array], graph):
-        """Pure program function: (fields, graph) → (fields, trips[i32[n_iters]]).
+        """Pure program function: (fields, graph) → (fields,
+        trips[i32[n_iters]], frontier[i32[n_iters, FRONTIER_TRIPS]]).
 
         Folds the (by default fused) :class:`~repro.core.plan.ProgramPlan`
         into one trace: superstep parts execute in plan order against the
@@ -106,15 +120,27 @@ class CompiledProgram:
         buffers ride the ``lax.while_loop`` carry — the loop-back edge of
         §4.3.2 iteration fusion, traced for real.
 
+        ``frontier[i, t]`` is the number of vertices whose fix fields
+        changed on trip ``t`` of loop ``i`` (all entries of a nested loop
+        in turn), the count whose zero ends the loop; trips from
+        :data:`FRONTIER_TRIPS` on are added into the last slot, and a loop
+        without fix fields counts nothing.
+
         ``graph`` is a traced argument (the compile-time graph or any graph
         of the same static shape): a closed-over graph would be embedded in
         the HLO as constants, which at chip scale exceeds the 2 GB
         serialization limit.
         """
-        pp = self.program_plan()
-        trips0 = jnp.zeros((max(self.n_iters, 1),), jnp.int32)
+        with plan_scope():
+            return self._fn(fields, graph)
 
-        def run_items(items, flds, mailbox, trips):
+    def _fn(self, fields, graph):
+        pp = self.program_plan()
+        n_loops = max(self.n_iters, 1)
+        trips0 = jnp.zeros((n_loops,), jnp.int32)
+        frontier0 = jnp.zeros((n_loops, FRONTIER_TRIPS), jnp.int32)
+
+        def run_items(items, flds, mailbox, counters):
             for it in items:
                 if isinstance(it, plan_mod.Superstep):
                     for ref in it.parts:
@@ -137,33 +163,41 @@ class CompiledProgram:
 
                 def cond(carry, _limit=limit):
                     _, _, _, changed, k = carry
-                    return jnp.logical_and(changed, k < _limit)
+                    with jax.named_scope("fixpoint"):
+                        return jnp.logical_and(changed, k < _limit)
 
                 def body(carry, _it=it, _fix=fix):
-                    f, m, t, _, k = carry
-                    new_f, m, t = run_items(_it.body, f, m, t)
-                    if _fix:
-                        changed = jnp.asarray(False)
-                        for name in _fix:
-                            changed = jnp.logical_or(
-                                changed, jnp.any(new_f[name] != f[name])
+                    f, m, c, _, k = carry
+                    new_f, m, (t, fr) = run_items(_it.body, f, m, c)
+                    i = _it.iter_index
+                    with jax.named_scope("fixpoint"):
+                        if _fix:
+                            count = frontier_count(f, new_f, _fix)
+                            at = (i, jnp.minimum(t[i], FRONTIER_TRIPS - 1))
+                            seen = jax.lax.dynamic_slice(fr, at, (1, 1))
+                            fr = jax.lax.dynamic_update_slice(
+                                fr, seen + count, at
                             )
-                    else:
-                        changed = jnp.asarray(True)  # fixed-trip iteration
-                    t = t.at[_it.iter_index].add(1)
-                    return new_f, m, t, changed, k + 1
+                            changed = count > 0
+                        else:
+                            changed = jnp.asarray(True)  # fixed-trip iteration
+                        t = t.at[i].add(1)
+                        return new_f, m, (t, fr), changed, k + 1
 
                 carry = (
-                    flds, mailbox, trips,
+                    flds, mailbox, counters,
                     jnp.asarray(True), jnp.asarray(0, jnp.int32),
                 )
-                flds, mailbox, trips, _, _ = jax.lax.while_loop(
-                    cond, body, carry
-                )
-            return flds, mailbox, trips
+                with jax.named_scope(f"L{it.iter_index}"):
+                    flds, mailbox, counters, _, _ = jax.lax.while_loop(
+                        cond, body, carry
+                    )
+            return flds, mailbox, counters
 
-        out_fields, _, trips = run_items(pp.items, dict(fields), {}, trips0)
-        return out_fields, trips
+        out_fields, _, (trips, frontier) = run_items(
+            pp.items, dict(fields), {}, (trips0, frontier0)
+        )
+        return out_fields, trips, frontier
 
     @functools.cached_property
     def _jitted_fn(self):
@@ -175,14 +209,28 @@ class CompiledProgram:
         user_fields: Optional[Dict[str, jax.Array]] = None,
         jit: bool = True,
     ):
-        """Execute; returns (fields, trips, superstep counts per regime)."""
-        fields = self.init_fields(user_fields)
+        """Execute; returns (fields, trips, counts): the superstep counts
+        per regime and, under ``"active_sets"``, the frontier of every
+        trip of every loop (:meth:`fn`; the shape of
+        ``BSPResult.active_sets`` for loops that are not nested)."""
+        with span("init_fields"):
+            fields = self.init_fields(user_fields)
         fn = self._jitted_fn if jit else self.fn
-        out, trips = fn(fields, self.graph)
-        trips_host = [int(x) for x in trips]
+        with span("execute"):
+            out, trips, frontier = fn(fields, self.graph)
+        with span("read_counters"):
+            trips, frontier = jax.device_get((trips, frontier))
+        trips_host = trips.tolist()
         counts = {
             name: cm.count(trips_host) for name, cm in self.cost_models.items()
         }
+        counts["active_sets"] = [
+            frontier[i, : min(n, FRONTIER_TRIPS)].tolist()
+            if node.fix_fields else []
+            for i, (node, n) in enumerate(
+                zip(_iter_nodes(self.prog), trips_host)
+            )
+        ]
         return out, trips_host, counts
 
 
@@ -229,6 +277,7 @@ def _discover_fields(prog, graph, fields_struct):
     return go(prog, dict(fields_struct))
 
 
+@span("compile_program")
 def compile_program(
     source_or_ast: Union[str, ast.Prog],
     graph,
@@ -261,11 +310,12 @@ def compile_program(
     expansion for A/B comparisons. Results are bit-identical either way —
     fusion moves superstep boundaries, never reorders primitive ops.
     """
-    prog = (
-        palgol_parser.parse(source_or_ast)
-        if isinstance(source_or_ast, str)
-        else source_or_ast
-    )
+    with span("parse"):
+        prog = (
+            palgol_parser.parse(source_or_ast)
+            if isinstance(source_or_ast, str)
+            else source_or_ast
+        )
     if schedule is not None and schedule not in SCHEDULES:
         raise CompileError(
             f"unknown schedule {schedule!r}; expected one of {SCHEDULES}"
@@ -277,8 +327,10 @@ def compile_program(
     for name, arr in (initial_fields or {}).items():
         arr = jnp.asarray(arr)
         fs[name] = jax.ShapeDtypeStruct(arr.shape, arr.dtype)
-    field_struct = _discover_fields(prog, graph, fs)
-    cost_models = stm_mod.superstep_report(prog, byte_costs=byte_costs)
+    with span("discover_fields"):
+        field_struct = _discover_fields(prog, graph, fs)
+    with span("cost_models"):
+        cost_models = stm_mod.superstep_report(prog, byte_costs=byte_costs)
     return CompiledProgram(
         prog=prog,
         graph=graph,

@@ -599,10 +599,11 @@ class OpRef:
     """One primitive plan op with its owning step context.
 
     ``plan`` is the owning :class:`StepPlan` (None for IterInit/StopOp);
-    ``sidx`` is the step ordinal in program order — the executors' mailbox
-    namespace, so two steps materializing the same chain pattern cannot
-    collide once supersteps from different steps share a program-level
-    mailbox.
+    ``sidx`` is the step's ordinal in program order, StopSteps counted
+    (-1 for IterInit) — the executors' mailbox namespace, so two steps
+    materializing the same chain pattern cannot collide once supersteps
+    from different steps share a program-level mailbox, and the ``s<sidx>``
+    of the step's device-work names (:mod:`repro.core.codegen`).
     """
 
     op: object  # ReadRound | MainCompute | RemoteUpdate | IterInit | StopOp
@@ -762,7 +763,9 @@ def lower_program(
                 for i, op in enumerate(plan.ops)
             ]
         if isinstance(p, ast.StopStep):
-            return [Superstep((OpRef(StopOp(p)),), head=True)]
+            si = sidx[0]
+            sidx[0] += 1
+            return [Superstep((OpRef(StopOp(p), sidx=si),), head=True)]
         if isinstance(p, ast.Seq):
             out: List[object] = []
             for q in p.progs:

@@ -45,7 +45,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core import plan as plan_mod
-from repro.core.codegen import HALTED, _EdgeCtx, exec_plan_part
+from repro.core.codegen import HALTED, _EdgeCtx, exec_plan_part, plan_scope
 from repro.core.plan import ByteCostModel
 from repro.graph import ops as gops
 from repro.graph.partition import halo
@@ -178,8 +178,11 @@ def _local_view(pg: PartitionedGraph) -> PartitionedGraph:
     )
 
 
-def _make_superstep_fn(ss: plan_mod.Superstep, pg: PartitionedGraph, mesh):
-    """jit(jax.shard_map(...)) executing ONE fused superstep's parts in order.
+def _make_superstep_fn(
+    ss: plan_mod.Superstep, pg: PartitionedGraph, mesh, loops: tuple = ()
+):
+    """jit(jax.shard_map(...)) executing ONE fused superstep's parts in order,
+    named inside the loops ``loops`` (:func:`repro.core.codegen.plan_scope`).
 
     ``(fields, mailbox, pg) -> (fields, mailbox)`` over per-shard blocks;
     the specs are pytree prefixes (every fields/mailbox leaf is a
@@ -196,8 +199,11 @@ def _make_superstep_fn(ss: plan_mod.Superstep, pg: PartitionedGraph, mesh):
         comm = ShardComm(pgl)
         local_f = {k: v[0] for k, v in flds.items()}
         local_m = tmap(lambda v: v[0], mbox)
-        for ref in ss.parts:
-            local_f, local_m = exec_plan_part(ref, pgl, comm, local_f, local_m)
+        with plan_scope(loops):
+            for ref in ss.parts:
+                local_f, local_m = exec_plan_part(
+                    ref, pgl, comm, local_f, local_m
+                )
         return (
             {k: v[None] for k, v in local_f.items()},
             tmap(lambda v: v[None], local_m),
@@ -275,9 +281,9 @@ def run_bsp_partitioned(
     ss_fns: Dict[int, object] = {}
     mailbox_box = [{}]
 
-    def exec_superstep(ss: plan_mod.Superstep, flds):
+    def exec_superstep(ss: plan_mod.Superstep, flds, loops):
         if id(ss) not in ss_fns:
-            ss_fns[id(ss)] = _make_superstep_fn(ss, pg, mesh)
+            ss_fns[id(ss)] = _make_superstep_fn(ss, pg, mesh, loops)
         flds, mailbox_box[0] = ss_fns[id(ss)](flds, mailbox_box[0], pg)
         return flds
 

@@ -27,8 +27,15 @@ expansion — same results, more supersteps.
   the byte model when ``byte_costs`` is given);
 * fixed-point termination is checked on host between supersteps, exactly like
   Pregel's aggregator round-trip; the per-iteration frontier size (how many
-  vertices' fix fields changed) is recorded in ``BSPResult.active_sets`` —
-  the live request-set instrumentation the byte cost model feeds on.
+  vertices' fix fields changed, :func:`repro.core.codegen.frontier_count`,
+  the count the fused dense loop keeps too) is recorded in
+  ``BSPResult.active_sets`` — the live request-set instrumentation the
+  byte cost model feeds on.
+
+Each dispatch's device work carries the names of
+:mod:`repro.core.codegen` (``palgol/L<i>/s<sidx>/<leaf>``), and the walk
+marks each dispatch and each frontier round trip with a host span
+(:func:`repro.trace.span`).
 
 The executed-superstep count is returned and cross-checked in tests against
 the STM cost models of ``repro.core.stm`` — both count the same fused plan.
@@ -37,6 +44,7 @@ the STM cost models of ``repro.core.stm`` — both count the same fused plan.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional
 
 import jax
@@ -44,7 +52,14 @@ import jax.numpy as jnp
 
 from repro.core import ast
 from repro.core import plan as plan_mod
-from repro.core.codegen import HALTED, StepExecutor, _RemoteMsg, make_stop_fn
+from repro.core.codegen import (
+    HALTED,
+    StepExecutor,
+    _RemoteMsg,
+    frontier_count,
+    make_stop_fn,
+    plan_scope,
+)
 from repro.core.plan import (
     ByteCostModel,
     ReadRound,
@@ -53,6 +68,7 @@ from repro.core.plan import (
     lower_step,
 )
 from repro.graph import ops as gops
+from repro.trace import span
 
 
 @dataclasses.dataclass
@@ -145,11 +161,12 @@ class _StagedStep:
                 # requester u pushes its id to the owner vertex (real
                 # scatter: the message traffic manual Pregel code pays)
                 out = dict(mailbox)
-                for ce in _op.chains:
-                    owner = self._lookup(fields, out, ce.prefix)
-                    out[self._key(ce.pattern) + ":req"] = (
-                        self._combine_requests(owner, "set")
-                    )
+                with jax.named_scope("chain"):
+                    for ce in _op.chains:
+                        owner = self._lookup(fields, out, ce.prefix)
+                        out[self._key(ce.pattern) + ":req"] = (
+                            self._combine_requests(owner, "set")
+                        )
                 return out
 
             return request
@@ -161,13 +178,16 @@ class _StagedStep:
                 # along the chain, message-combined per owner (one slot
                 # per distinct owner — the scatter-min IS the combiner)
                 out = dict(mailbox)
-                for send in _op.sends:
-                    owner = self._resolve(fields, out, send.target)
-                    if owner is None:
-                        continue
-                    out[self._pkey(send.target) + ":req"] = (
-                        self._combine_requests(owner, _op.combiner or "min")
-                    )
+                with jax.named_scope("chain"):
+                    for send in _op.sends:
+                        owner = self._resolve(fields, out, send.target)
+                        if owner is None:
+                            continue
+                        out[self._pkey(send.target) + ":req"] = (
+                            self._combine_requests(
+                                owner, _op.combiner or "min"
+                            )
+                        )
                 return out
 
             return push_request
@@ -179,24 +199,8 @@ class _StagedStep:
             # with the request set segment-combined per owner;
             # "nbr_send": per-edge buffers
             out = dict(mailbox)
-            for ce in _op.chains:
-                pre = self._lookup(fields, out, ce.prefix)
-                suf = self._lookup(fields, out, ce.suffix)
-                val = gops.gather(suf, pre)
-                if _op.kind == "push_reply":
-                    # combine concurrent requests per owner (Pregel message
-                    # combining; the combiner op is plan-recorded) and fold
-                    # the combined buffer into the reply — the term is
-                    # exactly zero, but the simplifier can't prove it, so
-                    # the combining scatter survives into the lowering
-                    reqbuf = self._combine_requests(
-                        pre, _op.combiner or "min"
-                    )
-                    val = val + (
-                        gops.gather(reqbuf, pre) // (self.n + 2)
-                    ).astype(val.dtype)
-                out[self._key(ce.pattern)] = val
-                out.pop(self._key(ce.pattern) + ":req", None)
+            with jax.named_scope("chain"):
+                self._reply(fields, out, _op)
             if _op.kind == "push_reply":
                 # the paired push_request's address buffers were the wire
                 # accounting of *their* superstep; done — drop them so
@@ -204,13 +208,33 @@ class _StagedStep:
                 prefix = self.ns + "pushaddr:"
                 for k in [k for k in out if k.startswith(prefix)]:
                     out.pop(k)
-            for direction, npat in _op.nbr_sends:
-                nbr, _, _, _ = graph.edges(direction)
-                val = self._lookup(fields, out, npat)
-                out[self._nkey(direction, npat)] = gops.gather(val, nbr)
+            with jax.named_scope("nbr"):
+                for direction, npat in _op.nbr_sends:
+                    nbr, _, _, _ = graph.edges(direction)
+                    val = self._lookup(fields, out, npat)
+                    out[self._nkey(direction, npat)] = gops.gather(val, nbr)
             return out
 
         return stage
+
+    def _reply(self, fields, out, op: ReadRound):
+        """A value round's chain gathers, into the mailbox ``out``."""
+        for ce in op.chains:
+            pre = self._lookup(fields, out, ce.prefix)
+            suf = self._lookup(fields, out, ce.suffix)
+            val = gops.gather(suf, pre)
+            if op.kind == "push_reply":
+                # combine concurrent requests per owner (Pregel message
+                # combining; the combiner op is plan-recorded) and fold
+                # the combined buffer into the reply — the term is
+                # exactly zero, but the simplifier can't prove it, so
+                # the combining scatter survives into the lowering
+                reqbuf = self._combine_requests(pre, op.combiner or "min")
+                val = val + (
+                    gops.gather(reqbuf, pre) // (self.n + 2)
+                ).astype(val.dtype)
+            out[self._key(ce.pattern)] = val
+            out.pop(self._key(ce.pattern) + ":req", None)
 
     def _resolve(self, fields, mailbox, pattern):
         """Pattern value if materialized/axiomatic, else None (push address
@@ -297,12 +321,21 @@ class _StagedStep:
         return update
 
 
+def _stop_part(f, m, g, stop):
+    with jax.named_scope("stop"):
+        return make_stop_fn(stop, g)(f), m
+
+
 def _make_staged_superstep_fn(
-    ss: plan_mod.Superstep, n_vertices: int, staged: Dict[int, _StagedStep]
+    ss: plan_mod.Superstep,
+    n_vertices: int,
+    staged: Dict[int, _StagedStep],
+    loops: tuple = (),
 ):
     """jit of ONE fused superstep's parts in order:
     ``(fields, mailbox, graph) -> (fields, mailbox)``. ``staged`` caches
-    one :class:`_StagedStep` per program step across supersteps."""
+    one :class:`_StagedStep` per program step across supersteps; ``loops``
+    are the iter indices of the loops the superstep runs in (its names)."""
     part_fns = []
     for ref in ss.parts:
         op = ref.op
@@ -310,7 +343,7 @@ def _make_staged_superstep_fn(
             continue
         if isinstance(op, plan_mod.StopOp):
             part_fns.append(
-                lambda f, m, g, _s=op.stop: (make_stop_fn(_s, g)(f), m)
+                (ref.sidx, functools.partial(_stop_part, stop=op.stop))
             )
             continue
         if ref.sidx not in staged:
@@ -318,11 +351,13 @@ def _make_staged_superstep_fn(
                 ref.plan.step, n_vertices, ref.plan.schedule,
                 plan=ref.plan, ns=f"s{ref.sidx}:",
             )
-        part_fns.append(staged[ref.sidx].op_fn(op))
+        part_fns.append((ref.sidx, staged[ref.sidx].op_fn(op)))
 
     def ss_fn(flds, mailbox, graph):
-        for fn in part_fns:
-            flds, mailbox = fn(flds, mailbox, graph)
+        with plan_scope(loops):
+            for sidx, fn in part_fns:
+                with jax.named_scope(f"s{sidx}"):
+                    flds, mailbox = fn(flds, mailbox, graph)
         return flds, mailbox
 
     return jax.jit(ss_fn)
@@ -333,19 +368,6 @@ def read_superstep_count(step: ast.Step, schedule: str) -> int:
     — ``lower_step(step).read_rounds``, the same plan every executor
     dispatches, so placements cannot diverge from the accounting."""
     return lower_step(step, schedule=schedule).read_rounds
-
-
-def _frontier_size(before, after, fix_fields, vertex_ndim: int) -> int:
-    """Vertices whose fix fields changed this iteration (the fixed-point
-    frontier). ``vertex_ndim`` is the number of leading per-vertex dims
-    (1 dense, 2 for ``[shard, row]``-blocked partitioned state)."""
-    changed = None
-    for f in fix_fields:
-        d = after[f] != before[f]
-        if d.ndim > vertex_ndim:
-            d = d.reshape(d.shape[:vertex_ndim] + (-1,)).any(axis=-1)
-        changed = d if changed is None else jnp.logical_or(changed, d)
-    return int(jnp.sum(changed))
 
 
 def walk_plan(
@@ -360,8 +382,9 @@ def walk_plan(
 ):
     """Host-side walk of a (fused) program plan, shared by every placement.
 
-    ``exec_superstep(superstep, fields)`` executes ONE plan superstep
-    (fused parts included) and returns the new fields; this walker owns
+    ``exec_superstep(superstep, fields, loops)`` executes ONE plan
+    superstep (fused parts included) inside the loops ``loops`` (iter
+    indices, outermost first) and returns the new fields; this walker owns
     sequencing, trip counting, the host-side OR-aggregator fixed-point
     check, the superstep counter (one per dispatched superstep — the fused
     accounting), and the per-iteration frontier instrumentation — so
@@ -369,10 +392,11 @@ def walk_plan(
     partitioned executors.
     """
 
-    def run(items, flds):
+    def run(items, flds, loops=()):
         for it in items:
             if isinstance(it, plan_mod.Superstep):
-                flds = exec_superstep(it, flds)
+                with span("superstep"):
+                    flds = exec_superstep(it, flds, loops)
                 counter[0] += 1
                 continue
             # PlanLoop
@@ -388,13 +412,14 @@ def walk_plan(
             )
             for _ in range(limit):
                 before = {f: flds[f] for f in node.fix_fields}
-                flds = run(it.body, flds)
+                flds = run(it.body, flds, loops + (it.iter_index,))
                 trips[slot] += 1
                 if node.fix_fields:
                     # host-side aggregator round-trip (Pregel OR-aggregator)
-                    frontier = _frontier_size(
-                        before, flds, node.fix_fields, vertex_ndim
-                    )
+                    with span("frontier"):
+                        frontier = int(frontier_count(
+                            before, flds, node.fix_fields, vertex_ndim
+                        ))
                     if active_sets is not None:
                         active_sets[slot].append(frontier)
                     if frontier == 0:
@@ -466,10 +491,10 @@ def run_bsp(
     ss_fns: Dict[int, object] = {}
     mailbox_box = [{}]
 
-    def exec_superstep(ss: plan_mod.Superstep, flds):
+    def exec_superstep(ss: plan_mod.Superstep, flds, loops):
         if id(ss) not in ss_fns:
             ss_fns[id(ss)] = _make_staged_superstep_fn(
-                ss, graph.n_vertices, staged
+                ss, graph.n_vertices, staged, loops
             )
         flds, mailbox_box[0] = ss_fns[id(ss)](flds, mailbox_box[0], graph)
         return flds

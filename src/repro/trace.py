@@ -1,0 +1,41 @@
+"""Host spans of the Palgol program.
+
+:func:`span` marks one phase of host work in two places at once:
+
+* the profiler trace, as a ``jax.profiler.TraceAnnotation`` named
+  ``palgol/<name>``, on the clock the device trace shares, so that a
+  reduction of the trace can say what the host did in a device idle gap;
+* ``jax.monitoring``, as one duration event ``/palgol/<name>`` in seconds,
+  for a listener registered with
+  ``jax.monitoring.register_event_duration_secs_listener`` (the mechanism
+  JAX's own compile-time events use).
+
+Spans wrap host phases only (``compile_program``'s front end,
+``CompiledProgram.run``'s phases, the staged walk's dispatches). Device
+work inside a jitted program is named with ``jax.named_scope`` instead,
+by :mod:`repro.core.codegen`; a span cannot see it.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import time
+
+import jax
+
+#: prefix of every monitoring event a span records
+EVENT_PREFIX = "/palgol/"
+
+
+@contextlib.contextmanager
+def span(name: str):
+    """Time the ``with`` body as the span ``name`` (see the module doc).
+    The event is recorded when the body ends, whether or not it raised."""
+    t0 = time.perf_counter()
+    with jax.profiler.TraceAnnotation(f"palgol/{name}"):
+        try:
+            yield
+        finally:
+            jax.monitoring.record_event_duration_secs(
+                EVENT_PREFIX + name, time.perf_counter() - t0
+            )

@@ -10,6 +10,8 @@ process at a time may load the TPU library.
 
 import functools
 import os
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -77,7 +79,7 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _graph_shapes(n_edges, sharding) -> Graph:
+def _graph_shapes(n_edges, sharding, n_vertices=N) -> Graph:
     i32, f32, b = jnp.int32, jnp.float32, jnp.bool_
 
     def arr(dtype):
@@ -86,7 +88,7 @@ def _graph_shapes(n_edges, sharding) -> Graph:
     return Graph(
         src=arr(i32), dst=arr(i32), weight=arr(f32), edge_mask=arr(b),
         t_src=arr(i32), t_dst=arr(i32), t_weight=arr(f32), t_mask=arr(b),
-        n_vertices=N, n_edges=n_edges,
+        n_vertices=n_vertices, n_edges=n_edges,
     )
 
 
@@ -111,6 +113,82 @@ def test_dense_program_compiles_with_graph_argument(one_chip, name, n_edges):
     # the edge arrays arrive as arguments (src, dst, mask at least)
     assert mem.argument_size_in_bytes >= 9 * n_edges
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+#: the on-chip benchmark's Palgol programs
+BENCH_PROGRAMS = (
+    Path(__file__).resolve().parents[1] / "benchmarks" / "palgol_chip"
+    / "programs"
+)
+#: HLO opcodes that hold or move data and compute nothing
+_TRIVIAL = {
+    "parameter", "constant", "tuple", "get-tuple-element", "bitcast",
+    "broadcast", "copy", "copy-start", "copy-done",
+}
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%\S+ = .*? ([a-z][a-z0-9\-]*)\(")
+
+
+def _instructions(hlo_text):
+    """``computation -> [(opcode, called computation, op_name, line)]``."""
+    out, name = {}, None
+    for line in hlo_text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            name = m.group(1)
+            out[name] = []
+            continue
+        m = _INSTRUCTION.match(line)
+        if m and name is not None:
+            calls = re.search(r"calls=%?([\w.\-]+)", line)
+            op_name = re.search(r'op_name="([^"]*)"', line)
+            out[name].append((
+                m.group(1), calls and calls.group(1),
+                op_name and op_name.group(1), line.strip(),
+            ))
+    return out
+
+
+@pytest.mark.parametrize(
+    "program,edge_factor", [("sv", 32), ("wcc", 32), ("sssp_rooted", 16)]
+)
+def test_every_op_of_a_benchmark_program_is_named(
+    one_chip, program, edge_factor
+):
+    """Every instruction of the chip's executable that computes carries a
+    ``palgol/`` name (``repro.core.codegen``'s plan-item scopes), so the
+    device trace can charge its time to a plan item. Data that XLA only
+    moves or broadcasts, and fusions of nothing else, are exempt."""
+    n = 1 << 10
+    text = (BENCH_PROGRAMS / f"{program}.palgol").read_text()
+    inputs = {"Root": np.zeros(n, bool)} if program == "sssp_rooted" else {}
+    tiny = from_edge_list(np.array([0]), np.array([1]), n)
+    cp = compile_program(text, tiny, initial_fields=inputs)
+    fields = {
+        k: _sds(v.shape, v.dtype, one_chip) for k, v in cp.field_struct.items()
+    }
+    graph = _graph_shapes(edge_factor * n, one_chip, n_vertices=n)
+    comps = _instructions(
+        jax.jit(cp.fn).lower(fields, graph).compile().as_text()
+    )
+
+    def trivial(opcode, calls):
+        return opcode in _TRIVIAL or (
+            opcode == "fusion"
+            and all(trivial(o, c) for o, c, _, _ in comps.get(calls, []))
+        )
+
+    named = unnamed = 0
+    for instrs in comps.values():
+        for opcode, calls, op_name, line in instrs:
+            if trivial(opcode, calls):
+                continue
+            if op_name and "palgol" in op_name.split("/"):
+                named += 1
+            else:
+                unnamed += 1
+                print("unnamed:", line)
+    assert named > 50 and unnamed == 0
 
 
 def _partitioned_graph_shapes(mesh, n_shards) -> PartitionedGraph:
