@@ -29,7 +29,8 @@ work falls under one of the leaves:
 * ``chain``: a ReadRound's chain gathers, the naive request scatter, and
   reads by computed address;
 * ``nbr``: neighbour access: per-edge gathers of neighbour values, edge
-  masks, segment reductions over edges;
+  masks, segment reductions over edges (a segmented scan and a read at
+  each run's end, or a scatter: ``StepExecutor._reduce_edges``);
 * ``remote``: building remote-write messages and applying them;
 * ``local``: the rest of the main computation;
 * ``stop``: a StopStep;
@@ -65,6 +66,7 @@ from repro.core.plan import (
     lower_step,
 )
 from repro.graph import ops as gops
+from repro.trace import count
 
 # NOTE: the deprecated ``codegen.CHAIN_MODE`` module global (PR 3's
 # one-release shim) is gone; the schedule is the explicit ``schedule=``
@@ -120,6 +122,9 @@ class _EdgeCtx:
     # addressing (== vid/nbr densely; local under a partitioned comm):
     seg: jax.Array = None  # row index of the current vertex (segment key)
     nbr_read: jax.Array = None  # address for reading per-row arrays at e.id
+    # i32[rows] one past each row's last slot of the seg-sorted edges
+    # (Graph.segment_ends); None where unknown (a partitioned comm)
+    ends: Optional[jax.Array] = None
 
     def __post_init__(self):
         if self.seg is None:
@@ -335,7 +340,10 @@ class StepExecutor:
         if self.comm is not None:
             return self.comm.edge_ctx(direction)
         nbr, vid, w, m = self.graph.edges(direction)
-        return _EdgeCtx(direction, nbr, vid, w, m)
+        return _EdgeCtx(
+            direction, nbr, vid, w, m,
+            ends=self.graph.segment_ends(direction),
+        )
 
     def _field(self, name: str) -> jax.Array:
         if name == "Id":
@@ -521,30 +529,40 @@ class StepExecutor:
             mask = jnp.logical_and(mask, fv)
         if e.func == "count":
             ones = jnp.ones_like(ectx.seg, dtype=jnp.int32)
-            return gops.segment_reduce(
-                ones, ectx.seg, self.nrows, "sum",
-                indices_are_sorted=True, mask=mask,
-            )
+            return self._reduce_edges(ones, ectx, "sum", mask)
         body = self._eval(e.body, ectx)
         body = jnp.asarray(body)
         if body.ndim == 0:
             body = jnp.broadcast_to(body, ectx.seg.shape)
         if e.func in ("argmin", "argmax"):
             comb = "min" if e.func == "argmin" else "max"
-            best = gops.segment_reduce(
-                body, ectx.seg, self.nrows, comb,
-                indices_are_sorted=True, mask=mask,
-            )
+            best = self._reduce_edges(body, ectx, comb, mask)
             attained = jnp.logical_and(mask, body == gops.gather(best, ectx.seg))
             ids = jnp.where(attained, ectx.nbr, self.n)
-            out = gops.segment_reduce(
-                ids, ectx.seg, self.nrows, "min", indices_are_sorted=True
-            )
+            out = self._reduce_edges(ids, ectx, "min")
             # empty segments reduce to int-max; clamp to the sentinel (numV)
             return jnp.minimum(out, self.n)
         comb = _REDUCE_TO_COMBINER[e.func]
+        return self._reduce_edges(body, ectx, comb, mask)
+
+    def _reduce_edges(self, values, ectx: _EdgeCtx, op: str, mask=None):
+        """Combine per-edge ``values`` into their current vertices with
+        ``op``. Where the context has run ends and ``op`` gives the same
+        bits in any order over ``values``' dtype, a segmented scan of the
+        sorted edges (:func:`repro.graph.ops.sorted_segment_reduce`);
+        otherwise the scatter. The path taken is recorded as the event
+        ``/palgol/edge_reduce/<scan|scatter>`` at trace time."""
+        if ectx.ends is not None and gops.is_order_independent(
+            op, values.dtype
+        ):
+            count("edge_reduce/scan")
+            return gops.sorted_segment_reduce(
+                values, ectx.seg, ectx.ends, op, mask=mask
+            )
+        count("edge_reduce/scatter")
         return gops.segment_reduce(
-            body, ectx.seg, self.nrows, comb, indices_are_sorted=True, mask=mask
+            values, ectx.seg, self.nrows, op, indices_are_sorted=True,
+            mask=mask,
         )
 
     # -- statement execution -------------------------------------------------
@@ -614,10 +632,7 @@ class StepExecutor:
                 raise CompileError(
                     f"field {s.field!r} must exist before accumulation in a loop"
                 )
-            seg = gops.segment_reduce(
-                val.astype(cur.dtype), ectx.seg, self.nrows, comb,
-                indices_are_sorted=True, mask=m,
-            )
+            seg = self._reduce_edges(val.astype(cur.dtype), ectx, comb, m)
             updated = _OP_APPLY[s.op](cur, seg).astype(cur.dtype)
             self.new[s.field] = jnp.where(self.active, updated, cur)
 

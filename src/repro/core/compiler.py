@@ -33,7 +33,8 @@ from repro.core.codegen import (
     plan_scope,
 )
 from repro.core.plan import ByteCostModel, SCHEDULES, lower_step
-from repro.trace import span
+from repro.graph.structure import with_segment_ends
+from repro.trace import counted, span
 
 # pre-order Iter list — the shared iteration-counter index order
 _iter_nodes = plan_mod.iter_nodes
@@ -61,6 +62,11 @@ class CompiledProgram:
     # program plan ``fn`` folds into its trace; False keeps the unfused
     # per-op expansion for A/B comparisons
     fuse: bool = True
+    #: edge reductions of the last traced ``fn`` by the path they took,
+    #: ``{"scan": k, "scatter": m}`` (``StepExecutor._reduce_edges``)
+    edge_reduce_paths: Optional[Dict[str, int]] = dataclasses.field(
+        default=None, init=False
+    )
 
     def step_plans(
         self, schedule: Optional[str] = None
@@ -129,10 +135,16 @@ class CompiledProgram:
         ``graph`` is a traced argument (the compile-time graph or any graph
         of the same static shape): a closed-over graph would be embedded in
         the HLO as constants, which at chip scale exceeds the 2 GB
-        serialization limit.
+        serialization limit. Its edge reductions scan the sorted edges
+        where it carries run ends (``Graph.in_ends``/``out_ends``), as
+        ``self.graph`` does; tracing sets :attr:`edge_reduce_paths`.
         """
-        with plan_scope():
-            return self._fn(fields, graph)
+        with counted("edge_reduce/") as paths, plan_scope():
+            out = self._fn(fields, graph)
+        self.edge_reduce_paths = {
+            "scan": paths["scan"], "scatter": paths["scatter"]
+        }
+        return out
 
     def _fn(self, fields, graph):
         pp = self.program_plan()
@@ -304,6 +316,11 @@ def compile_program(
     ``auto`` cost model is built with the same costs so the accounting
     tracks the selection.
 
+    ``graph`` is a :class:`~repro.graph.structure.Graph`. Where it lacks
+    the run ends of an ordering the program's edge lists read, they are
+    computed once, on its device (the span ``segment_ends``), and
+    :attr:`CompiledProgram.graph` is the graph that holds them.
+
     ``fuse`` (default True) applies the §4.3 program-level optimizations
     (state merging + iteration fusion, :func:`repro.core.plan.fuse`) to the
     plan the trace folds in; ``fuse=False`` keeps the unfused per-op
@@ -320,6 +337,12 @@ def compile_program(
         raise CompileError(
             f"unknown schedule {schedule!r}; expected one of {SCHEDULES}"
         )
+    directions = {
+        e.direction for e in ast.walk_exprs(prog) if isinstance(e, ast.EdgeList)
+    }
+    jax.block_until_ready(graph)  # the span times the ends alone
+    with span("segment_ends"):
+        graph = jax.block_until_ready(with_segment_ends(graph, directions))
     n = graph.n_vertices
     fs: Dict[str, jax.ShapeDtypeStruct] = {
         HALTED: jax.ShapeDtypeStruct((n,), jnp.bool_)

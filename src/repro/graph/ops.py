@@ -7,8 +7,9 @@ semantics.
 
 JAX has no native EmbeddingBag / CSR sparse; per the assignment, message
 passing over an edge-index → node scatter IS part of the system and lives
-here. The Pallas ``segment_reduce`` kernel (``repro.kernels``) is a drop-in
-replacement for :func:`segment_reduce` on TPU hot paths.
+here. Where the edges are sorted by segment and each segment's run end is
+known, an order-independent reduction needs no scatter:
+:func:`sorted_segment_reduce` scans the runs and reads each at its end.
 """
 
 from __future__ import annotations
@@ -115,6 +116,113 @@ def segment_reduce(
         # empty segments reduce to INT_MAX; identity of `and` is True
         return jnp.minimum(asint, 1).astype(jnp.bool_)
     raise ValueError(f"unknown combiner {op!r}")
+
+
+def is_order_independent(op: str, dtype) -> bool:
+    """Whether combiner ``op`` over ``dtype`` gives the same bits in any
+    order of its values: ``min``, ``max``, ``and``, ``or`` and integer
+    ``sum``. A float ``sum`` or ``prod`` rounds differently in another
+    order, so only the scatter reproduces it."""
+    if op in ("min", "max", "and", "or"):
+        return True
+    return op == "sum" and jnp.issubdtype(dtype, jnp.integer)
+
+
+#: slots per row of the segmented scan: one row of lanes
+_SCAN_ROW = 128
+
+
+def _shift_right(x: jax.Array, k: int, fill) -> jax.Array:
+    """``x`` moved ``k`` places along axis 1, ``fill`` in the first ``k``."""
+    widths = [(0, 0)] * x.ndim
+    widths[1] = (k, 0)
+    return jnp.pad(x[:, :-k], widths, constant_values=fill)
+
+
+def _segmented_scan(x, seg, fn, ident) -> jax.Array:
+    """Inclusive scan of ``x`` with ``fn`` along axis 0 within each run of
+    equal ``seg`` (ascending, so a run is contiguous).
+
+    The slots are viewed as rows of :data:`_SCAN_ROW`, and every step
+    below is a Hillis-Steele step: after the step of shift ``k`` a slot
+    holds its run's values over the last ``2k`` slots, as a slot ``k``
+    back belongs to the same run exactly when its ``seg`` is equal. Each
+    row is scanned in log2 of its width steps of static shifts; the rows'
+    last slots are then scanned in a loop of steps over the rows, and each
+    row folds in the row before's result where its leading run continues
+    that row's last run. The loop keeps the trace's structure the same for
+    any number of rows.
+    """
+    e = x.shape[0]
+    width = _SCAN_ROW
+    rows = -(-e // width)
+    pad = rows * width - e
+    if pad:  # the padding continues the last run with the identity
+        x = jnp.concatenate([x, jnp.full((pad,) + x.shape[1:], ident, x.dtype)])
+        seg = jnp.concatenate([seg, jnp.broadcast_to(seg[-1:], (pad,))])
+    trailing = (1,) * (x.ndim - 1)  # seg broadcast over the value's dims
+    xs = x.reshape((rows, width) + x.shape[1:])
+    ss = seg.reshape((rows, width) + trailing)
+    k = 1
+    while k < width:
+        same = ss == _shift_right(ss, k, -1)
+        xs = jnp.where(same, fn(xs, _shift_right(xs, k, ident)), xs)
+        k *= 2
+    if rows > 1:
+        # each row's last slot, as reductions of the rows: its value is the
+        # row's only one that is not the identity, its seg the row's largest
+        last = (jnp.arange(width) == width - 1).reshape((1, width) + trailing)
+        tail = jax.lax.reduce(jnp.where(last, xs, ident), ident, fn, (1,))
+        tail_seg = jnp.max(ss, axis=1)
+        row = jnp.arange(rows).reshape((rows,) + trailing)
+
+        def step(i, acc):
+            k = jnp.left_shift(1, i)
+            same = (row >= k) & (jnp.roll(tail_seg, k, axis=0) == tail_seg)
+            return jnp.where(same, fn(acc, jnp.roll(acc, k, axis=0)), acc)
+
+        tails = jax.lax.fori_loop(0, (rows - 1).bit_length(), step, tail)
+        before = jnp.concatenate(
+            [jnp.full((1,) + tails.shape[1:], ident, tails.dtype), tails[:-1]]
+        )
+        before_seg = jnp.concatenate(
+            [jnp.full((1,) + trailing, -1, ss.dtype), tail_seg[:-1]]
+        )
+        cont = ss == before_seg[:, None]
+        xs = jnp.where(cont, fn(xs, before[:, None]), xs)
+    return xs.reshape((rows * width,) + x.shape[1:])[:e]
+
+
+def sorted_segment_reduce(
+    values: jax.Array,
+    segment_ids: jax.Array,
+    ends: jax.Array,
+    op: str,
+    mask: Optional[jax.Array] = None,
+) -> jax.Array:
+    """Reduce ``values`` over runs of ascending ``segment_ids``, without a
+    scatter: :func:`segment_reduce` with ``num_segments = len(ends)`` for
+    an order-independent ``op`` (:func:`is_order_independent`), to the bit.
+
+    ``ends[v]`` is one past the last slot of segment ``v`` (``searchsorted``
+    of ``segment_ids`` on the right); ids at or past ``len(ends)``, such as
+    the padding sentinel, sort last and are read by no segment. A segmented
+    inclusive scan runs along the slots, and each segment reads it at its
+    run's last slot; a segment with no slot gets the combiner's identity.
+    """
+    if not is_order_independent(op, values.dtype):
+        raise ValueError(f"{op!r} over {values.dtype} depends on the order")
+    ident = _identity_for(op, values.dtype)
+    if values.shape[0] == 0:
+        return jnp.full(ends.shape + values.shape[1:], ident)
+    if mask is not None:
+        mshape = mask.shape + (1,) * (values.ndim - mask.ndim)
+        values = jnp.where(mask.reshape(mshape), values, ident)
+    scan = _segmented_scan(values, segment_ids, COMBINE_FN[op], ident)
+    starts = jnp.concatenate([jnp.zeros((1,), ends.dtype), ends[:-1]])
+    last = jnp.take(scan, jnp.maximum(ends - 1, 0), axis=0, mode="clip")
+    nonempty = (ends > starts).reshape(ends.shape + (1,) * (values.ndim - 1))
+    return jnp.where(nonempty, last, ident)
 
 
 def gather(field: jax.Array, idx: jax.Array, fill=None) -> jax.Array:

@@ -18,11 +18,18 @@ Conventions
   and ``mask = False``. All consumers either segment-reduce with explicit
   ``num_segments=n_vertices`` (sentinel rows are dropped by scatter's
   ``mode="drop"``) or mask messages to the combiner identity first.
+* ``in_ends``/``out_ends`` give each vertex's run of slots in the ``dst``
+  and ``t_src`` orderings: ``ends[v]`` is one past its last slot, so its
+  run is ``ends[v-1]:ends[v]``. They let an order-independent reduction
+  scan the sorted slots instead of scattering
+  (:func:`repro.graph.ops.sorted_segment_reduce`). ``None`` where not
+  computed: :func:`with_segment_ends` fills them.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -50,9 +57,18 @@ class Graph:
     n_vertices: int = dataclasses.field(metadata=dict(static=True))
     n_edges: int = dataclasses.field(metadata=dict(static=True))
 
+    # run ends (module doc), i32[n_vertices] each, or None
+    in_ends: Optional[jax.Array] = None  # in the dst ordering
+    out_ends: Optional[jax.Array] = None  # in the t_src ordering
+
     @property
     def sentinel(self) -> int:
         return self.n_vertices
+
+    def segment_ends(self, direction: str) -> Optional[jax.Array]:
+        """The run ends of the ordering :meth:`edges` gives for
+        ``direction``, or ``None`` where they are not computed."""
+        return self.out_ends if direction == "out" else self.in_ends
 
     def in_edges(self):
         """(neighbor_id, self_id, weight, mask) for pull-along-In traversal."""
@@ -73,6 +89,41 @@ class Graph:
         if direction == "out":
             return self.out_edges()
         raise ValueError(f"unknown edge direction {direction!r}")
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _run_ends(sorted_ids: jax.Array, n_vertices: int) -> jax.Array:
+    """``searchsorted(sorted_ids, arange(n_vertices), side="right")``
+    without a search per vertex: one sort brings the slots where a run
+    ends to the front, in order, each writes one past itself at its
+    vertex, and a vertex with no slot takes the end of the vertex before."""
+    e = sorted_ids.shape[0]
+    if e == 0:
+        return jnp.zeros((n_vertices,), jnp.int32)
+    last = jnp.concatenate(
+        [sorted_ids[1:] != sorted_ids[:-1], jnp.ones((1,), jnp.bool_)]
+    )
+    last = jnp.logical_and(last, sorted_ids < n_vertices)
+    # at most n_vertices runs end; every other slot sorts after them as e
+    at = jnp.sort(jnp.where(last, jnp.arange(e, dtype=jnp.int32), e))
+    at = at[:n_vertices]
+    vertex = jnp.where(at < e, jnp.take(sorted_ids, at, mode="clip"), n_vertices)
+    ends = jnp.zeros((n_vertices,), jnp.int32).at[vertex].set(
+        at + 1, mode="drop"
+    )
+    return jax.lax.cummax(ends)
+
+
+def with_segment_ends(graph: Graph, directions) -> Graph:
+    """``graph`` with the run ends of every ordering that an edge list of
+    ``directions`` (``"in"``, ``"nbr"``, ``"out"``) reads, computed on the
+    graph's device where they are missing."""
+    ends = {}
+    if graph.in_ends is None and {"in", "nbr"} & set(directions):
+        ends["in_ends"] = _run_ends(graph.dst, graph.n_vertices)
+    if graph.out_ends is None and "out" in directions:
+        ends["out_ends"] = _run_ends(graph.t_src, graph.n_vertices)
+    return dataclasses.replace(graph, **ends) if ends else graph
 
 
 def stable_argsort(key) -> np.ndarray:
@@ -142,6 +193,7 @@ def from_edge_list(
     dst_s, src_s, w_s, m_s = _sort_by(dst_p, dst_p, src_p, w_p, mask_p)
     # push ordering: sorted by src
     tsrc_s, tdst_s, tw_s, tm_s = _sort_by(src_p, src_p, dst_p, w_p, mask_p)
+    vertices = np.arange(n_vertices)
 
     return Graph(
         src=jnp.asarray(src_s),
@@ -154,6 +206,12 @@ def from_edge_list(
         t_mask=jnp.asarray(tm_s),
         n_vertices=int(n_vertices),
         n_edges=int(n_edges),
+        in_ends=jnp.asarray(
+            np.searchsorted(dst_s, vertices, side="right").astype(np.int32)
+        ),
+        out_ends=jnp.asarray(
+            np.searchsorted(tsrc_s, vertices, side="right").astype(np.int32)
+        ),
     )
 
 

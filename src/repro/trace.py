@@ -10,6 +10,11 @@
   ``jax.monitoring.register_event_duration_secs_listener`` (the mechanism
   JAX's own compile-time events use).
 
+:func:`count` records a plain ``jax.monitoring`` event ``/palgol/<name>``
+where the program makes a choice at trace time (which path an edge
+reduction takes); :func:`counted` tallies such events over a ``with``
+body.
+
 Spans wrap host phases only (``compile_program``'s front end,
 ``CompiledProgram.run``'s phases, the staged walk's dispatches). Device
 work inside a jitted program is named with ``jax.named_scope`` instead,
@@ -18,12 +23,13 @@ by :mod:`repro.core.codegen`; a span cannot see it.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import time
 
 import jax
 
-#: prefix of every monitoring event a span records
+#: prefix of every monitoring event a span or a count records
 EVENT_PREFIX = "/palgol/"
 
 
@@ -39,3 +45,27 @@ def span(name: str):
             jax.monitoring.record_event_duration_secs(
                 EVENT_PREFIX + name, time.perf_counter() - t0
             )
+
+
+def count(name: str) -> None:
+    """Record one ``jax.monitoring`` event ``/palgol/<name>``."""
+    jax.monitoring.record_event(EVENT_PREFIX + name)
+
+
+@contextlib.contextmanager
+def counted(prefix: str):
+    """Tally the events ``/palgol/<prefix><rest>`` recorded in the ``with``
+    body, in this process, into the yielded ``Counter`` keyed by
+    ``<rest>``."""
+    tally = collections.Counter()
+    head = EVENT_PREFIX + prefix
+
+    def listen(event: str, **_):
+        if event.startswith(head):
+            tally[event[len(head):]] += 1
+
+    jax.monitoring.register_event_listener(listen)
+    try:
+        yield tally
+    finally:
+        jax.monitoring.unregister_event_listener(listen)

@@ -171,8 +171,8 @@ def test_the_program_spans_its_front_end_run_and_walk():
     with _Events() as ev:
         cp = compile_program(alg.WCC, g)
     assert ev.names == [
-        "/palgol/parse", "/palgol/discover_fields", "/palgol/cost_models",
-        "/palgol/compile_program",
+        "/palgol/parse", "/palgol/segment_ends", "/palgol/discover_fields",
+        "/palgol/cost_models", "/palgol/compile_program",
     ]
     with _Events() as ev:
         cp.run()
