@@ -14,9 +14,14 @@ shard — and each superstep moves only *boundary* state:
   request/reply reads — pointer doubling rebuilds its request set from the
   current indirection field every round), ``scatter_reduce`` (combiner-aware
   reduce-scatter for remote writes);
-* :mod:`~repro.graph.partition.executor` — ``run_bsp_partitioned``: the
-  ``placement="partitioned"`` path of ``repro.pregel.run_bsp``, executing
-  unchanged Palgol programs over the partitioned layout;
+* :mod:`~repro.graph.partition.on_mesh` — ``partition_on_mesh``: the same
+  ``PartitionedGraph`` built on the chips from an edge list already spread
+  over the mesh (the host ``partition_graph`` is its plain reference);
+* :mod:`~repro.graph.partition.executor` — ``PartitionedProgram`` (a
+  program prepared once per graph and mesh, run many times) and
+  ``run_bsp_partitioned``: the ``placement="partitioned"`` path of
+  ``repro.pregel.run_bsp``, executing unchanged Palgol programs over the
+  partitioned layout;
 * :mod:`~repro.graph.partition.stats` — communication accounting feeding
   ``benchmarks/palgol_mesh.py``, and ``byte_cost_model`` — the measured
   halo/request-set figures instrumented into a
@@ -34,7 +39,14 @@ from repro.graph.partition.partitioner import (  # noqa: F401
     unpartition_field,
     unpartition_fields,
 )
+from repro.graph.partition.on_mesh import (  # noqa: F401
+    partition_on_mesh,
+    route,
+    sort_blocks,
+    sorted_length,
+)
 from repro.graph.partition.executor import (  # noqa: F401
+    PartitionedProgram,
     run_bsp_partitioned,
 )
 from repro.graph.partition.stats import (  # noqa: F401

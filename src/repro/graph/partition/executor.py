@@ -1,6 +1,10 @@
 """`placement="partitioned"` execution of Palgol programs.
 
-``run_bsp_partitioned`` is the partitioned twin of
+:class:`PartitionedProgram` prepares a program once per
+(program, :class:`~repro.graph.partition.partitioner.PartitionedGraph`,
+mesh, schedule, fuse) and runs it as many times as asked;
+``run_bsp_partitioned`` builds one and runs it once. Either is the
+partitioned twin of
 :func:`repro.pregel.runtime.run_bsp`: the same host-side program-plan walk
 (:func:`repro.pregel.runtime.walk_plan` — Seq/Iter/Stop sequencing,
 fixed-point aggregator round-trips, fused superstep counting, frontier
@@ -36,13 +40,14 @@ STM cross-checks carry over by construction, for every schedule and both
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
-
-from jax.sharding import PartitionSpec as P
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import plan as plan_mod
 from repro.core.codegen import HALTED, _EdgeCtx, exec_plan_part, plan_scope
@@ -51,11 +56,12 @@ from repro.graph import ops as gops
 from repro.graph.partition import halo
 from repro.graph.partition.partitioner import (
     PartitionedGraph,
-    partition_fields,
+    join_rows,
     partition_graph,
-    unpartition_fields,
+    split_rows,
 )
 from repro.pregel.runtime import BSPResult, walk_plan
+from repro.trace import summed
 
 AXIS = halo.AXIS
 
@@ -223,6 +229,124 @@ def _make_superstep_fn(
 # the runtime
 
 
+def _mesh_of(pg: PartitionedGraph):
+    """The 1-D ``shard`` mesh ``pg``'s blocks are split over, or ``None``."""
+    sharding = getattr(pg.src_g, "sharding", None)
+    mesh = getattr(sharding, "mesh", None)
+    if mesh is not None and tuple(mesh.axis_names) == (AXIS,):
+        return mesh
+    return None
+
+
+class PartitionedProgram:
+    """A Palgol program prepared for one :class:`PartitionedGraph` on one
+    mesh: the (fused) plan, lowered once, and one jitted ``shard_map`` per
+    plan superstep, each traced and compiled on its first dispatch and
+    reused by every later job. :meth:`run` walks the plan with
+    :func:`~repro.pregel.runtime.walk_plan`; a second ``run`` over the same
+    graph obtains no executable.
+
+    ``mesh`` defaults to the mesh ``pg``'s blocks already lie on (as
+    :func:`~repro.graph.partition.partition_on_mesh` leaves them), else a
+    1-D mesh over the first ``pg.n_shards`` devices. Fields go in and come
+    out dense (``[N, ...]``), as with ``run_bsp``.
+    """
+
+    def __init__(
+        self,
+        prog,
+        pg: PartitionedGraph,
+        mesh=None,
+        schedule: str = "pull",
+        byte_costs: Optional[ByteCostModel] = None,
+        fuse: bool = True,
+        max_iters: int = 100_000,
+    ):
+        from repro.dist import sharding as shd
+
+        self.plan = plan_mod.lower_program(
+            prog, schedule=schedule, byte_costs=byte_costs
+        )
+        if fuse:
+            self.plan = plan_mod.fuse(self.plan)
+        if mesh is None:
+            mesh = _mesh_of(pg) or shd.shard_mesh(pg.n_shards)
+        if mesh.shape[AXIS] != pg.n_shards:
+            raise ValueError(
+                f"graph has {pg.n_shards} shards, mesh has {mesh.shape[AXIS]}"
+            )
+        self.mesh = mesh
+        self.max_iters = max_iters
+        self.pg = jax.device_put(pg, shd.vertex_partition_shardings(pg, mesh))
+        bounds = tuple(int(b) for b in np.asarray(pg.starts))
+        self._partition = jax.jit(
+            lambda fields: {k: split_rows(bounds, pg.v_max, v)
+                            for k, v in fields.items()},
+            out_shardings=NamedSharding(mesh, P(AXIS)),
+        )
+        self._unpartition = jax.jit(
+            lambda fields: {k: join_rows(bounds, v) for k, v in fields.items()}
+        )
+        self._fns: Dict[int, object] = {}
+        #: per superstep: bytes its collectives carry per chip per dispatch
+        self._carried: Dict[int, Dict[str, float]] = {}
+
+    def _dispatch(self, ss: plan_mod.Superstep, loops, flds, mailbox):
+        key = id(ss)
+        if key in self._fns:
+            return self._fns[key](flds, mailbox, self.pg)
+        self._fns[key] = _make_superstep_fn(ss, self.pg, self.mesh, loops)
+        # the first dispatch traces the superstep: its collectives record
+        # what their operands carry
+        with summed("comm/") as carried:
+            out = self._fns[key](flds, mailbox, self.pg)
+        self._carried[key] = dict(carried)
+        return out
+
+    def run(
+        self, fields: Dict[str, jax.Array], max_iters: Optional[int] = None
+    ) -> BSPResult:
+        """One job from the canonical dense field dict ``fields``; a loop
+        without a fixed trip count stops after ``max_iters`` trips (default:
+        the program's ``max_iters``)."""
+        fields = {k: jnp.asarray(v) for k, v in fields.items()}
+        if HALTED not in fields:
+            fields[HALTED] = jnp.zeros((self.pg.n_vertices,), jnp.bool_)
+        counter = [0]
+        trips: List[int] = []
+        active_sets: List[List[int]] = []
+        mailbox_box = [{}]
+        comm_bytes: collections.Counter = collections.Counter()
+
+        def exec_superstep(ss: plan_mod.Superstep, flds, loops):
+            flds, mailbox_box[0] = self._dispatch(
+                ss, loops, flds, mailbox_box[0]
+            )
+            comm_bytes.update(self._carried[id(ss)])
+            return flds
+
+        out = walk_plan(
+            self.plan, self._partition(fields), exec_superstep, counter,
+            trips, self.max_iters if max_iters is None else max_iters,
+            active_sets=active_sets, vertex_ndim=2,
+        )
+        return BSPResult(
+            fields=self._unpartition(out),
+            supersteps=counter[0],
+            trips=trips,
+            active_sets=active_sets,
+            comm_bytes=dict(comm_bytes),
+        )
+
+    def warm(self, fields: Dict[str, jax.Array]) -> None:
+        """Obtain every executable a job from ``fields`` uses by running
+        one job cut to two trips of each loop: every superstep is
+        dispatched, on the first trip and on one that follows a trip, and
+        so are the walk's frontier counts and the partitioning of the
+        fields in and out."""
+        jax.block_until_ready(self.run(fields, max_iters=2).fields)
+
+
 def run_bsp_partitioned(
     prog,
     graph,
@@ -234,7 +358,8 @@ def run_bsp_partitioned(
     byte_costs: Optional[ByteCostModel] = None,
     fuse: bool = True,
 ) -> BSPResult:
-    """Execute a Palgol program over partitioned vertex state.
+    """Execute a Palgol program over partitioned vertex state: build a
+    :class:`PartitionedProgram` and run it once.
 
     Same contract as :func:`repro.pregel.runtime.run_bsp` (canonical field
     dict in, final *dense* fields + superstep count + trips + frontier
@@ -242,7 +367,8 @@ def run_bsp_partitioned(
     mesh over all local devices, built by
     :func:`repro.dist.sharding.shard_mesh`). ``graph`` may already be a
     :class:`PartitionedGraph` with one shard per mesh device, so a graph
-    partitioned once serves many jobs. Every schedule runs here
+    partitioned once serves many jobs (a :class:`PartitionedProgram` also
+    keeps the compiled supersteps between them). Every schedule runs here
     (``pull``/``push``/``naive``/``auto`` — build byte costs from this
     layout with :func:`repro.graph.partition.byte_cost_model`), and
     ``fuse=True`` (default) dispatches the §4.3-fused program plan — one
@@ -250,10 +376,6 @@ def run_bsp_partitioned(
     one dispatch; ``fuse=False`` dispatches the unfused per-op expansion.
     """
     from repro.dist import sharding as shd
-
-    pp = plan_mod.lower_program(prog, schedule=schedule, byte_costs=byte_costs)
-    if fuse:
-        pp = plan_mod.fuse(pp)
 
     if mesh is None:
         mesh = shd.shard_mesh(n_shards)
@@ -266,34 +388,7 @@ def run_bsp_partitioned(
         pg = graph
     else:
         pg = partition_graph(graph, n_shards)
-    fields = {k: jnp.asarray(v) for k, v in fields.items()}
-    if HALTED not in fields:
-        fields[HALTED] = jnp.zeros((pg.n_vertices,), jnp.bool_)
-    pfields = partition_fields(pg, fields)
-    pfields = jax.device_put(
-        pfields, shd.vertex_partition_shardings(pfields, mesh)
-    )
-    pg = jax.device_put(pg, shd.vertex_partition_shardings(pg, mesh))
-
-    counter = [0]
-    trips: List[int] = []
-    active_sets: List[List[int]] = []
-    ss_fns: Dict[int, object] = {}
-    mailbox_box = [{}]
-
-    def exec_superstep(ss: plan_mod.Superstep, flds, loops):
-        if id(ss) not in ss_fns:
-            ss_fns[id(ss)] = _make_superstep_fn(ss, pg, mesh, loops)
-        flds, mailbox_box[0] = ss_fns[id(ss)](flds, mailbox_box[0], pg)
-        return flds
-
-    out = walk_plan(
-        pp, pfields, exec_superstep, counter, trips, max_iters,
-        active_sets=active_sets, vertex_ndim=2,
-    )
-    return BSPResult(
-        fields=unpartition_fields(pg, out),
-        supersteps=counter[0],
-        trips=trips,
-        active_sets=active_sets,
-    )
+    return PartitionedProgram(
+        prog, pg, mesh, schedule=schedule, byte_costs=byte_costs, fuse=fuse,
+        max_iters=max_iters,
+    ).run(fields)

@@ -28,18 +28,44 @@ communication primitives cover all of Palgol's remote data access:
     tree-combine for the other monoids) lands each owner's combined delta.
     Targets are data-dependent, so unlike ``halo_exchange`` this pays
     O(N/S·S) worst-case — the price of Palgol's arbitrary remote writes.
+
+Each collective runs under a ``jax.named_scope`` of its own
+(``halo_exchange``, ``gather_global/request``, ``gather_global/reply``,
+``scatter_reduce``), inside the plan item's ``palgol/…`` scope, so that a
+device trace tells them apart. When a collective is traced, the bytes
+its operand carries per chip are recorded (:func:`repro.trace.tally`,
+events ``/palgol/comm/<primitive>/<padded|payload>``): ``padded`` is the
+operand as its static shape has it, what the collective moves;
+``payload`` is what an exchange of only the live rows would carry — a
+reader's ``n_ghost`` ghost rows for ``halo_exchange``, one request id and
+one reply per read for ``gather_global``, one (target id, value) pair per
+message for ``scatter_reduce``.
 """
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro import trace
 from repro.graph import ops as gops
 
 AXIS = "shard"
+
+
+def _row_bytes(x: jax.Array) -> int:
+    """Bytes of one row (everything past the leading dim) of ``x``."""
+    return math.prod(x.shape[1:]) * x.dtype.itemsize
+
+
+def _carried(primitive: str, padded: int, payload: int) -> None:
+    """Record the bytes a collective of ``primitive`` carries per chip
+    (see the module doc); called while the collective is traced."""
+    trace.tally(f"comm/{primitive}/padded", padded)
+    trace.tally(f"comm/{primitive}/payload", payload)
 
 
 def halo_exchange(
@@ -53,7 +79,10 @@ def halo_exchange(
     if n_ghost == 0:
         return jnp.zeros((0,) + x.shape[1:], x.dtype)
     vals = gops.gather(x, send_local)  # [S, Hp, ...] (pad rows clip: unread)
-    recv = jax.lax.all_to_all(vals, axis, split_axis=0, concat_axis=0)
+    _carried("halo_exchange", vals.shape[0] * vals.shape[1] * _row_bytes(x),
+             n_ghost * _row_bytes(x))
+    with jax.named_scope("halo_exchange"):
+        recv = jax.lax.all_to_all(vals, axis, split_axis=0, concat_axis=0)
     ghost = jnp.zeros((n_ghost + 1,) + x.shape[1:], x.dtype)
     ghost = ghost.at[recv_pos].set(recv, mode="drop")
     return ghost[:n_ghost]
@@ -123,9 +152,13 @@ def gather_global(
     local = (idxc - starts[owner]).astype(jnp.int32)
     req = jnp.full((n_shards, k), v_max, jnp.int32)
     req = req.at[owner, slot].set(local)
-    req_t = jax.lax.all_to_all(req, axis, split_axis=0, concat_axis=0)
+    _carried("gather_global", n_shards * k * (4 + _row_bytes(x)),
+             k * (4 + _row_bytes(x)))
+    with jax.named_scope("gather_global/request"):
+        req_t = jax.lax.all_to_all(req, axis, split_axis=0, concat_axis=0)
     vals = gops.gather(x, req_t)  # [S, K, ...]; padded slots clip, unread
-    vals_t = jax.lax.all_to_all(vals, axis, split_axis=0, concat_axis=0)
+    with jax.named_scope("gather_global/reply"):
+        vals_t = jax.lax.all_to_all(vals, axis, split_axis=0, concat_axis=0)
     out = vals_t[owner, slot]
     if fill is not None:
         import numpy as np
@@ -174,12 +207,21 @@ def scatter_reduce(
     padded = gops.scatter_combine(padded, pos, values, op_eff)
     if n_shards == 1:
         out = padded
-    elif op_eff == "sum":
-        out = jax.lax.psum_scatter(padded, axis, scatter_dimension=0, tiled=True)
     else:
-        blocks = padded.reshape((n_shards, v_max) + padded.shape[1:])
-        recv = jax.lax.all_to_all(blocks, axis, split_axis=0, concat_axis=0)
-        out = gops.combine_along_axis(op_eff, recv, axis=0)
+        _carried("scatter_reduce", padded.shape[0] * _row_bytes(padded),
+                 idx.shape[0] * (4 + _row_bytes(values)))
+        if op_eff == "sum":
+            with jax.named_scope("scatter_reduce"):
+                out = jax.lax.psum_scatter(
+                    padded, axis, scatter_dimension=0, tiled=True
+                )
+        else:
+            blocks = padded.reshape((n_shards, v_max) + padded.shape[1:])
+            with jax.named_scope("scatter_reduce"):
+                recv = jax.lax.all_to_all(
+                    blocks, axis, split_axis=0, concat_axis=0
+                )
+            out = gops.combine_along_axis(op_eff, recv, axis=0)
     if bool_io:
         thresh = {"or": jnp.maximum(out, 0) > 0, "and": jnp.minimum(out, 1) > 0}
         return thresh[op] if op in thresh else out.astype(jnp.bool_)

@@ -295,33 +295,35 @@ def partition_graph(
 # field (de)partitioning — host-side layout shuffles
 
 
-def _bounds_np(pg: PartitionedGraph) -> np.ndarray:
-    return np.asarray(pg.starts, dtype=np.int64)
+def _bounds(pg: PartitionedGraph) -> tuple:
+    return tuple(int(b) for b in np.asarray(pg.starts))
+
+
+def split_rows(bounds: tuple, v_max: int, x: jax.Array) -> jax.Array:
+    """``[N, ...]`` → ``[S, v_max, ...]`` blocks of the contiguous ranges
+    ``bounds`` (host ints), padding rows zero; traceable under ``jit``."""
+    return jnp.stack([
+        jnp.pad(x[lo:hi], [(0, v_max - (hi - lo))] + [(0, 0)] * (x.ndim - 1))
+        for lo, hi in zip(bounds[:-1], bounds[1:])
+    ])
+
+
+def join_rows(bounds: tuple, y: jax.Array) -> jax.Array:
+    """``[S, v_max, ...]`` blocks of the ranges ``bounds`` → ``[N, ...]``."""
+    return jnp.concatenate([
+        y[s, : hi - lo] for s, (lo, hi) in enumerate(zip(bounds[:-1], bounds[1:]))
+    ])
 
 
 def partition_field(pg: PartitionedGraph, x) -> jax.Array:
     """``[N, ...]`` dense vertex field → ``[S, v_max, ...]`` shard blocks
     (padding rows zero-filled; they are masked inactive by the executor)."""
-    x = jnp.asarray(x)
-    bounds = _bounds_np(pg)
-    idx = bounds[:-1, None] + np.arange(pg.v_max)[None, :]
-    valid = idx < bounds[1:, None]
-    gathered = jnp.take(x, jnp.asarray(np.clip(idx, 0, pg.n_vertices - 1)), axis=0)
-    vshape = valid.shape + (1,) * (gathered.ndim - 2)
-    return jnp.where(
-        jnp.asarray(valid).reshape(vshape), gathered, jnp.zeros((), x.dtype)
-    )
+    return split_rows(_bounds(pg), pg.v_max, jnp.asarray(x))
 
 
 def unpartition_field(pg: PartitionedGraph, y) -> jax.Array:
     """``[S, v_max, ...]`` shard blocks → ``[N, ...]`` dense vertex field."""
-    y = jnp.asarray(y)
-    bounds = _bounds_np(pg)
-    g = np.arange(pg.n_vertices, dtype=np.int64)
-    owner = np.searchsorted(bounds, g, side="right") - 1
-    flat_pos = owner * pg.v_max + (g - bounds[owner])
-    flat = y.reshape((pg.n_shards * pg.v_max,) + y.shape[2:])
-    return jnp.take(flat, jnp.asarray(flat_pos), axis=0)
+    return join_rows(_bounds(pg), jnp.asarray(y))
 
 
 def partition_fields(pg: PartitionedGraph, fields: Dict) -> Dict:

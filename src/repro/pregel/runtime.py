@@ -80,6 +80,9 @@ class BSPResult:
     # changed that iteration (the fixed-point frontier — the measured
     # request-set size ByteCostModel.request_set models)
     active_sets: List[List[int]] = dataclasses.field(default_factory=list)
+    # partitioned placement: bytes per chip the job's collectives carried,
+    # ``"<primitive>/<padded|payload>"`` (repro.graph.partition.halo)
+    comm_bytes: Dict[str, float] = dataclasses.field(default_factory=dict)
 
 
 class _StagedStep:
@@ -426,7 +429,13 @@ def walk_plan(
                         break
         return flds
 
-    return run(pp.items, fields)
+    try:
+        return run(pp.items, fields)
+    finally:
+        # ``run`` refers to itself: empty its cell, or the cycle would keep
+        # ``exec_superstep`` (and the graph and mailbox it closes over)
+        # alive until the garbage collector next runs
+        del run
 
 
 def run_bsp(

@@ -13,7 +13,9 @@
 :func:`count` records a plain ``jax.monitoring`` event ``/palgol/<name>``
 where the program makes a choice at trace time (which path an edge
 reduction takes); :func:`counted` tallies such events over a ``with``
-body.
+body. :func:`tally` records a scalar event ``/palgol/<name>`` where the
+program computes a size at trace time (the bytes a collective carries);
+:func:`summed` adds such amounts up over a ``with`` body.
 
 Spans wrap host phases only (``compile_program``'s front end,
 ``CompiledProgram.run``'s phases, the staged walk's dispatches). Device
@@ -69,3 +71,29 @@ def counted(prefix: str):
         yield tally
     finally:
         jax.monitoring.unregister_event_listener(listen)
+
+
+def tally(name: str, amount: float) -> None:
+    """Record ``amount`` under the ``jax.monitoring`` scalar event
+    ``/palgol/<name>`` (a size the program computes at trace time, such as
+    the bytes a collective's operands carry)."""
+    jax.monitoring.record_scalar(EVENT_PREFIX + name, amount)
+
+
+@contextlib.contextmanager
+def summed(prefix: str):
+    """Sum the amounts of the scalar events ``/palgol/<prefix><rest>``
+    recorded in the ``with`` body, in this process, into the yielded
+    ``Counter`` keyed by ``<rest>``."""
+    totals = collections.Counter()
+    head = EVENT_PREFIX + prefix
+
+    def listen(event: str, value, **_):
+        if event.startswith(head):
+            totals[event[len(head):]] += value
+
+    jax.monitoring.register_scalar_listener(listen)
+    try:
+        yield totals
+    finally:
+        jax.monitoring.unregister_scalar_listener(listen)

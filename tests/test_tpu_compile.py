@@ -9,6 +9,7 @@ process at a time may load the TPU library.
 """
 
 import functools
+import math
 import os
 import re
 from pathlib import Path
@@ -285,3 +286,119 @@ def test_embedding_bag_compiles(one_chip):
         _sds((N, 128), jnp.float32, one_chip),
         _sds((256, 8), jnp.int32, one_chip),
     ).compile()
+
+
+#: the sv-part4-g500-24 cell: Graph500 scale 24 over four chips, at the
+#: static sizes ``graphs/kronecker_mesh.py`` rounds to (6 significant
+#: bits): edge slots a chip hands the constructor, the routing capacity,
+#: ``e_max`` and ``v_max`` as a chip run read them (or above), and ghost
+#: counts at their bound (every vertex a shard does not own; a run read
+#: 4,587,520 ghosts and a pair capacity of 1,507,328)
+PART24 = dict(n=1 << 24, k=66 << 21, cap=66 << 19, e_max=63 << 21,
+              v_max=33 << 17, n_ghost=48 << 18, pair_cap=33 << 17)
+
+
+def _fits(compiled, resident=0):
+    """Per-device bytes of one executable (arguments, outputs, temporaries)
+    plus ``resident`` bytes held beside it, against a v5e's HBM."""
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes + resident)
+    assert used < V5E_HBM_BYTES, used
+    return used
+
+
+@pytest.mark.parametrize("stage", ["sizes", "route", "finish", "halo"])
+def test_partition_on_mesh_passes_fit_a_v5e_at_scale_24(mesh4, stage):
+    """The constructor's passes at the cell's sizes. The sort between route
+    and finish is a plain two-array ``lax.sort`` of 2**27 slots a chip
+    (2.7 GB with its operands); it takes about three minutes to compile
+    and is left out here."""
+    from repro.graph.partition import on_mesh
+
+    p, S = PART24, 4
+    split = NamedSharding(mesh4, P("shard"))
+    whole = NamedSharding(mesh4, P())
+    i32, b = jnp.int32, jnp.bool_
+    edges = [_sds((S * p["k"],), dt, split) for dt in (i32, i32, b)]
+    bounds = _sds((S + 1,), i32, whole)
+    length = on_mesh.sorted_length(max(S * p["cap"], p["e_max"]))
+    block = _sds((S * length,), i32, split)  # a sorted block
+    # the edge list a chip was handed stays live through every pass, the
+    # pull ordering's blocks (13 B a slot) through the push ordering's
+    held = p["k"] * 9 + (0 if stage == "sizes" else p["e_max"] * 13)
+    if stage == "sizes":
+        fn, args = on_mesh._sizes_fn(mesh4, p["n"]), edges
+    elif stage == "route":
+        fn = on_mesh._route_fn(mesh4, p["n"], p["cap"], length, False)
+        args = edges + [bounds]
+    elif stage == "finish":
+        fn = on_mesh._finish_fn(mesh4, p["n"], p["e_max"], p["v_max"], False)
+        args = [block, block, bounds]
+    else:
+        fn = on_mesh._halo_fn(mesh4, p["n"], p["v_max"], p["n_ghost"],
+                              p["pair_cap"])
+        args = [_sds((S, p["e_max"]), i32, split),
+                _sds((S, p["e_max"]), b, split),
+                _sds((S, p["n"]), b, split), bounds]
+    compiled = fn.lower(*args).compile()
+    _fits(compiled, resident=held)
+    if stage in ("route", "halo"):
+        assert "all-to-all" in compiled.as_text()
+
+
+def test_partitioned_sv_supersteps_fit_a_v5e_at_scale_24(mesh4):
+    """Every superstep S-V's fused plan dispatches, on the scale-24 graph
+    resident on four chips, beside the graph itself."""
+    p, S = PART24, 4
+    split = NamedSharding(mesh4, P("shard"))
+    whole = NamedSharding(mesh4, P())
+    i32, f32, b = jnp.int32, jnp.float32, jnp.bool_
+    e_max, v_max, H, Hp = p["e_max"], p["v_max"], p["n_ghost"], p["pair_cap"]
+
+    def blk(dtype, *shape):
+        return _sds((S,) + shape, dtype, split)
+
+    def halo():
+        return HaloSpec(ghost_ids=blk(i32, H), send_local=blk(i32, S, Hp),
+                        recv_pos=blk(i32, S, Hp), n_ghost=H, pair_cap=Hp)
+
+    pg = PartitionedGraph(
+        starts=_sds((S + 1,), i32, whole), vmask=blk(b, v_max),
+        src_g=blk(i32, e_max), src_h=blk(i32, e_max), dst_l=blk(i32, e_max),
+        w=blk(f32, e_max), emask=blk(b, e_max),
+        t_dst_g=blk(i32, e_max), t_dst_h=blk(i32, e_max),
+        t_src_l=blk(i32, e_max), t_w=blk(f32, e_max), t_emask=blk(b, e_max),
+        halo_in=halo(), halo_out=halo(), n_vertices=p["n"],
+        n_edges=S * e_max, n_shards=S, v_max=v_max, e_max=e_max,
+    )
+    graph_bytes = sum(
+        math.prod(x.shape[1:]) * x.dtype.itemsize
+        for x in jax.tree_util.tree_leaves(pg) if x.shape[0] == S
+    )
+    text = (BENCH_PROGRAMS / "sv.palgol").read_text()
+    tiny = from_edge_list(np.array([0]), np.array([1]), p["n"])
+    cp = compile_program(text, tiny)
+    pp = plan_mod.fuse(plan_mod.lower_program(cp.prog, schedule="pull"))
+    state = [{k: blk(v.dtype, v_max, *v.shape[1:])
+              for k, v in cp.field_struct.items()}, {}]
+    compiled_steps = []
+
+    def walk(items, loops=()):
+        for it in items:
+            if not isinstance(it, plan_mod.Superstep):
+                walk(it.body, loops + (it.iter_index,))
+                continue
+            lowered = _make_superstep_fn(it, pg, mesh4, loops).lower(
+                *state, pg)
+            compiled = lowered.compile()
+            # the leaves it reads arrive as arguments; the whole graph is
+            # held besides (what it reads is counted twice: a bound)
+            _fits(compiled, resident=graph_bytes)
+            compiled_steps.append(compiled)
+            state[:] = jax.tree_util.tree_map(
+                lambda o: _sds(o.shape, o.dtype, split), lowered.out_info)
+
+    walk(pp.items)
+    assert len(compiled_steps) >= 2
+    assert any("all-to-all" in c.as_text() for c in compiled_steps)
