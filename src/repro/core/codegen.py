@@ -40,19 +40,31 @@ and a loop's termination test and frontier count under ``fixpoint``
 reduction inside a local ``let``) nests, so the innermost leaf names the
 work. The dense compiler's loops are ``lax.while_loop`` s, so JAX puts
 ``while/body`` between ``L<i>`` and the parts in its names.
+
+An edge comprehension whose filters read only the neighbour folds them
+into the value it gathers (:func:`_fold_of`): ``minimum [D[e.id] + e.w |
+e <- In[v], A[e.id]]`` forms ``T[u] = A[u] ? D[u] : inf`` once per vertex
+and gathers ``T`` per edge, one gather where there were two. The plan's
+ReadRound prefetches ``T`` under its own mailbox key in place of the reads
+only the fold used; each fold traced records the event
+``/palgol/edge_reduce/fold``.
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, FrozenSet, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.core import ast
-from repro.core.analysis import CompileError, chain_pattern_of
+from repro.core.analysis import (
+    CompileError,
+    chain_pattern_of,
+    neighbor_pattern_of,
+)
 from repro.core.logic import PullSolver
 from repro.core.plan import (
     HALTED,
@@ -103,6 +115,7 @@ def frontier_count(before, after, fix_fields, vertex_ndim: int = 1):
 
 
 _REDUCE_TO_COMBINER = {
+    "count": "sum",
     "minimum": "min",
     "maximum": "max",
     "sum": "sum",
@@ -110,6 +123,135 @@ _REDUCE_TO_COMBINER = {
     "and": "and",
     "or": "or",
 }
+
+
+@dataclasses.dataclass(frozen=True)
+class _Fold:
+    """An edge comprehension whose neighbour-only filters fold into the
+    value it gathers (:func:`_fold_of`): per vertex ``T[u] = guard(u) ?
+    value(u) : identity``, then one gather of ``T`` per edge, with
+    ``term`` added per edge where there is one."""
+
+    reduce: ast.Reduce
+    guard: Tuple[ast.Expr, ...]  # the neighbour-only filters
+    rest: Tuple[ast.Expr, ...]  # the other filters, applied per edge
+    value: Optional[ast.Expr]  # neighbour-only; None for count (1)
+    term: Optional[ast.Expr]  # ``e.w``, added per edge
+    term_first: bool  # the body is ``term + value``
+    reads: FrozenSet[Tuple[str, tuple]]  # (direction, pattern) of T
+    key: int = 0  # ordinal among the step's folds: its mailbox key
+
+
+def _nbr_only(e: ast.Expr, edge_var: str) -> bool:
+    """Whether ``e`` reads nothing but ``e.id``, fields through it and
+    constants."""
+    if isinstance(e, ast.Const):
+        return True
+    if isinstance(e, ast.Var):
+        return e.name == "numV"
+    if isinstance(e, ast.EdgeProp):
+        return e.edge_var == edge_var and e.prop == "id"
+    if isinstance(e, ast.FieldAccess):
+        return neighbor_pattern_of(e, edge_var) is not None
+    if isinstance(e, (ast.Cond, ast.BinOp, ast.UnOp)):
+        return all(_nbr_only(x, edge_var) for x in _subtrees(e))
+    return False
+
+
+def _subtrees(node):
+    """The expressions and statements directly under ``node``."""
+    for f in dataclasses.fields(node):
+        v = getattr(node, f.name)
+        for c in v if isinstance(v, tuple) else (v,):
+            if isinstance(c, (ast.Expr, ast.Stmt)):
+                yield c
+
+
+def _nbr_reads(nodes, ctx=None, folds=None) -> set:
+    """``(direction, pattern)`` of every neighbour read under ``nodes``,
+    leaving out what the comprehensions of ``folds`` read through their
+    ``T``; ``ctx`` is the enclosing ``(direction, edge_var)``."""
+    out = set()
+    folds = folds or {}
+    for node in nodes:
+        kids, inner = None, ctx
+        if isinstance(node, ast.Reduce):
+            fold = folds.get(node)
+            kids = (node.body,) + node.filters if fold is None else fold.rest
+            inner = (node.range.direction, node.edge_var)
+        elif isinstance(node, ast.ForEdges):
+            kids, inner = node.body, (node.range.direction, node.edge_var)
+        elif ctx is not None and isinstance(node, ast.FieldAccess):
+            pat = neighbor_pattern_of(node, ctx[1])
+            if pat is not None:
+                out.add((ctx[0], pat))
+                continue
+        if kids is None:
+            kids = tuple(_subtrees(node))
+        out |= _nbr_reads(kids, inner, folds)
+    return out
+
+
+def _plus_weight(body: ast.Expr, edge_var: str):
+    """``(x, weight_first)`` where ``body`` is ``x + e.w`` or ``e.w + x``
+    with ``x`` a neighbour field, else ``None``."""
+    if not (isinstance(body, ast.BinOp) and body.op == "+"):
+        return None
+    w = ast.EdgeProp(edge_var, "w")
+    for x, other, first in (
+        (body.left, body.right, False), (body.right, body.left, True)
+    ):
+        if other == w and isinstance(x, ast.FieldAccess) and (
+            neighbor_pattern_of(x, edge_var) is not None
+        ):
+            return x, first
+    return None
+
+
+def _fold_of(r: ast.Reduce) -> Optional[_Fold]:
+    """The fold of comprehension ``r``, or ``None`` where it keeps its
+    filters per edge. It needs a neighbour-only filter (:func:`_nbr_only`)
+    that reads a field, and one of:
+
+    1. a neighbour-only body (or ``count``), under any reduction but
+       ``argmin``/``argmax``: each edge then carries what it carried,
+       the body where the guard holds and the identity where not;
+    2. under ``minimum``/``maximum``, a body ``x + e.w`` (or ``e.w + x``)
+       with ``x`` a neighbour field: an edge whose guard fails carries
+       ``identity + e.w``, the identity where ``x`` is a float and no live
+       weight is ``-identity`` or NaN (:meth:`StepExecutor._fold_holds`).
+       A constant in place of ``e.w`` leaves the body neighbour-only: 1.
+    """
+    if r.func in ("argmin", "argmax"):
+        return None
+    ev = r.edge_var
+    guard = tuple(f for f in r.filters if _nbr_only(f, ev))
+    rest = tuple(f for f in r.filters if not _nbr_only(f, ev))
+    ctx = (r.range.direction, ev)
+    if not _nbr_reads(guard, ctx):
+        return None
+    value, term, first = None, None, False
+    if r.func != "count":
+        if _nbr_only(r.body, ev):
+            value = r.body
+        elif r.func in ("minimum", "maximum") and (
+            split := _plus_weight(r.body, ev)
+        ):
+            (value, first), term = split, ast.EdgeProp(ev, "w")
+        else:
+            return None
+    reads = _nbr_reads(guard + ((value,) if value is not None else ()), ctx)
+    return _Fold(r, guard, rest, value, term, first, frozenset(reads))
+
+
+def needs_weight_bounds(prog: ast.Prog) -> bool:
+    """Whether a comprehension of ``prog`` folds only where the graph's
+    live weights are bounded (:attr:`repro.graph.structure.Graph.weights_bounded`)."""
+    for e in ast.walk_exprs(prog):
+        fold = _fold_of(e) if isinstance(e, ast.Reduce) else None
+        if fold is not None and fold.term is not None:
+            return True
+    return False
 
 
 @dataclasses.dataclass
@@ -151,6 +293,7 @@ class _StepState:
 
     chain: Dict[tuple, jax.Array] = dataclasses.field(default_factory=dict)
     nbr: Dict[tuple, jax.Array] = dataclasses.field(default_factory=dict)
+    fold: Dict[int, jax.Array] = dataclasses.field(default_factory=dict)
     pending: List[_RemoteMsg] = dataclasses.field(default_factory=list)
     naive_req: Dict[tuple, jax.Array] = dataclasses.field(default_factory=dict)
 
@@ -195,6 +338,9 @@ class StepExecutor:
         self.pull = PullSolver()
         self._leaf: Optional[str] = None
         self._active: Optional[jax.Array] = None
+        self.fold_cache: Dict[int, jax.Array] = {}
+        self._folds: Dict[ast.Reduce, _Fold] = {}
+        self._fold_only: set = set()
 
     # -- public -------------------------------------------------------------
     def __call__(
@@ -203,14 +349,16 @@ class StepExecutor:
         chain_values: Optional[Dict[tuple, jax.Array]] = None,
         split_remote: bool = False,
         nbr_values: Optional[Dict[tuple, jax.Array]] = None,
+        fold_values: Optional[Dict[int, jax.Array]] = None,
     ):
         """Execute the plan's ops in order (fused into this one trace).
 
         ``chain_values`` seeds the chain cache with buffers materialized by
         earlier remote-reading supersteps (BSP mode) — seeded ReadRound
         work is skipped; ``nbr_values`` seeds per-edge neighborhood buffers
-        keyed by ``(direction, pattern)``. In dense mode the rounds inline
-        their gathers here instead.
+        keyed by ``(direction, pattern)``, ``fold_values`` the gathered
+        ``T`` of each fold keyed by its ordinal. In dense mode the rounds
+        inline their gathers here instead.
         With ``split_remote=True`` returns ``(fields, pending_messages)`` so
         a separate remote-updating superstep can apply them (paper Fig. 9).
         """
@@ -219,10 +367,12 @@ class StepExecutor:
         self.env: Dict[str, Tuple[str, jax.Array]] = {}
         self.chain_cache: Dict[tuple, jax.Array] = dict(chain_values or {})
         self.nbr_cache: Dict[tuple, jax.Array] = dict(nbr_values or {})
+        self.fold_cache = dict(fold_values or {})
         self.expr_cache: Dict[Tuple[int, ast.Expr], jax.Array] = {}
         self.pending: List[_RemoteMsg] = []
         self._naive_req: Dict[tuple, jax.Array] = {}
         self._active = None
+        self._find_folds()
         for op in self.plan.ops:
             if isinstance(op, ReadRound):
                 self._exec_read_round(op)
@@ -262,10 +412,12 @@ class StepExecutor:
         self.env = {}
         self.chain_cache = dict(state.chain)
         self.nbr_cache = dict(state.nbr)
+        self.fold_cache = dict(state.fold)
         self.expr_cache = {}
         self.pending = list(state.pending)
         self._naive_req = dict(state.naive_req)
         self._active = None
+        self._find_folds()
         for op in ops:
             if isinstance(op, ReadRound):
                 self._exec_read_round(op)
@@ -280,6 +432,7 @@ class StepExecutor:
             # written; only materialized multi-hop buffers are the mailbox
             chain={p: v for p, v in self.chain_cache.items() if len(p) > 1},
             nbr=dict(self.nbr_cache),
+            fold=dict(self.fold_cache),
             pending=list(self.pending),
             naive_req=dict(self._naive_req),
         )
@@ -386,8 +539,18 @@ class StepExecutor:
         with self._in("chain"):
             self._read_chains(op)
         with self._in("nbr"):
+            for fold in self._folds.values():
+                if fold.key not in self.fold_cache and op == self._fold_round(
+                    fold
+                ):
+                    self.fold_cache[fold.key] = self._read_nbr(
+                        self._fold_table(fold),
+                        self._edge_ctx(fold.reduce.range.direction),
+                    )
             for direction, npat in op.nbr_sends:
-                if (direction, npat) in self.nbr_cache:
+                if (direction, npat) in self.nbr_cache or (
+                    direction, npat
+                ) in self._fold_only:
                     continue
                 per_vertex = self._chain_value(npat)
                 ectx = self._edge_ctx(direction)
@@ -497,8 +660,7 @@ class StepExecutor:
             rhs = self._eval(e.right, ectx)
             return _binop(e.op, lhs, rhs)
         if isinstance(e, ast.UnOp):
-            x = self._eval(e.operand, ectx)
-            return jnp.logical_not(x) if e.op == "!" else -x
+            return _unop(e.op, self._eval(e.operand, ectx))
         if isinstance(e, ast.Reduce):
             return self._eval_reduce(e)
         raise CompileError(f"cannot evaluate {type(e).__name__}")
@@ -523,13 +685,18 @@ class StepExecutor:
 
     def _edge_reduce(self, e: ast.Reduce) -> jax.Array:
         ectx = self._edge_ctx(e.range.direction)
+        fold = self._folds.get(e)
         mask = ectx.emask
-        for f in e.filters:
+        for f in e.filters if fold is None else fold.rest:
             fv = self._eval(f, ectx)
             mask = jnp.logical_and(mask, fv)
+        comb = _REDUCE_TO_COMBINER.get(e.func)
+        if fold is not None:
+            count("edge_reduce/fold")
+            return self._reduce_edges(self._folded(fold, ectx), ectx, comb, mask)
         if e.func == "count":
             ones = jnp.ones_like(ectx.seg, dtype=jnp.int32)
-            return self._reduce_edges(ones, ectx, "sum", mask)
+            return self._reduce_edges(ones, ectx, comb, mask)
         body = self._eval(e.body, ectx)
         body = jnp.asarray(body)
         if body.ndim == 0:
@@ -542,8 +709,86 @@ class StepExecutor:
             out = self._reduce_edges(ids, ectx, "min")
             # empty segments reduce to int-max; clamp to the sentinel (numV)
             return jnp.minimum(out, self.n)
-        comb = _REDUCE_TO_COMBINER[e.func]
         return self._reduce_edges(body, ectx, comb, mask)
+
+    # -- the neighbour-guard fold (module doc) --------------------------------
+    def _find_folds(self):
+        """The step's folds that hold over the fields and graph at hand, and
+        the neighbour reads only they consume. The same for every executor
+        of one step, so that a ReadRound prefetches what its main compute
+        reads."""
+        folds = {}
+        for e in ast.walk_exprs(self.step):
+            fold = _fold_of(e) if isinstance(e, ast.Reduce) else None
+            if fold is not None and e not in folds and self._fold_holds(fold):
+                folds[e] = dataclasses.replace(fold, key=len(folds))
+        self._folds = folds
+        consumed = set().union(*(f.reads for f in folds.values()))
+        self._fold_only = consumed - _nbr_reads(self.step.body, folds=folds)
+
+    def _fold_holds(self, fold: _Fold) -> bool:
+        """Whether a fold that adds ``e.w`` per edge is exact here
+        (:func:`_fold_of`, case 2): ``x`` a float, and no live weight
+        ``-identity`` or NaN by the graph's ``weights_bounded``."""
+        if fold.term is None:
+            return True
+        name = neighbor_pattern_of(fold.value, fold.reduce.edge_var)[-1]
+        if name not in self.old or not jnp.issubdtype(
+            self.old[name].dtype, jnp.floating
+        ):
+            return False
+        bounds = getattr(self.graph, "weights_bounded", None)
+        # minimum's identity +inf absorbs any w > -inf; maximum's, w < inf
+        return bounds is not None and bounds[fold.reduce.func == "maximum"]
+
+    def _fold_round(self, fold: _Fold) -> Optional[ReadRound]:
+        """The last of the plan's ReadRounds that sends a read of ``T``:
+        where ``T`` is formed and gathered."""
+        rounds = [
+            op for op in self.plan.ops
+            if isinstance(op, ReadRound) and fold.reads & set(op.nbr_sends)
+        ]
+        return rounds[-1] if rounds else None
+
+    def _fold_table(self, fold: _Fold) -> jax.Array:
+        """``T`` at every row: the value where the guard holds, else the
+        reduction's identity."""
+        guard = True
+        for f in fold.guard:
+            guard = jnp.logical_and(guard, self._at_vertex(f))
+        if fold.value is None:  # count
+            value = jnp.asarray(1, jnp.int32)
+        else:
+            value = jnp.asarray(self._at_vertex(fold.value))
+        comb = _REDUCE_TO_COMBINER[fold.reduce.func]
+        table = jnp.where(guard, value, gops._identity_for(comb, value.dtype))
+        return jnp.broadcast_to(table, (self.nrows,))
+
+    def _at_vertex(self, e: ast.Expr):
+        """A neighbour-only expression (:func:`_nbr_only`) at every row,
+        as an edge whose neighbour is that row would read it."""
+        if isinstance(e, ast.EdgeProp):
+            return self._ids()
+        if isinstance(e, ast.FieldAccess):
+            return self._chain_value(self._nbr_pattern(e))
+        if isinstance(e, ast.Cond):
+            return jnp.where(*(self._at_vertex(x) for x in _subtrees(e)))
+        if isinstance(e, ast.BinOp):
+            return _binop(e.op, self._at_vertex(e.left), self._at_vertex(e.right))
+        if isinstance(e, ast.UnOp):
+            return _unop(e.op, self._at_vertex(e.operand))
+        return self._eval(e, None)  # a constant, numV
+
+    def _folded(self, fold: _Fold, ectx: _EdgeCtx) -> jax.Array:
+        """The per-edge values of a folded comprehension: ``T`` at each
+        edge's neighbour (prefetched, or gathered here), plus the term."""
+        t = self.fold_cache.get(fold.key)
+        if t is None:
+            t = self._read_nbr(self._fold_table(fold), ectx)
+        if fold.term is None:
+            return t
+        c = self._eval(fold.term, ectx)
+        return _binop("+", c, t) if fold.term_first else _binop("+", t, c)
 
     def _reduce_edges(self, values, ectx: _EdgeCtx, op: str, mask=None):
         """Combine per-edge ``values`` into their current vertices with
@@ -704,6 +949,10 @@ def _fold_combiner(op: str, cur: jax.Array, delta: jax.Array) -> jax.Array:
     return gops.combine(op, cur, delta).astype(cur.dtype)
 
 
+def _unop(op: str, x):
+    return jnp.logical_not(x) if op == "!" else -x
+
+
 def _binop(op: str, lhs, rhs):
     if op == "+":
         return lhs + rhs
@@ -746,6 +995,7 @@ def _binop(op: str, lhs, rhs):
 #
 #   s<i>:chain:<f1>/<f2>...   materialized chain buffer (pattern-keyed)
 #   s<i>:nbr:<dir>:<f1>...    per-edge neighborhood buffer
+#   s<i>:fold:<k>             per-edge T of the step's fold k (_Fold.key)
 #   s<i>:req:<f1>/...         naive request buffer (dense wire emulation)
 #   s<i>:pending              remote-write payload (Main -> RemoteUpdate),
 #                             a tuple of (idx, values, mask) triples in
@@ -768,6 +1018,8 @@ def _ns_import(ns: str, mailbox, ru_writes) -> "_StepState":
         elif rest.startswith("nbr:"):
             _, direction, pat = rest.split(":", 2)
             state.nbr[(direction, tuple(pat.split("/")) if pat else ())] = v
+        elif rest.startswith("fold:"):
+            state.fold[int(rest[len("fold:"):])] = v
         elif rest.startswith("req:"):
             state.naive_req[tuple(rest[len("req:"):].split("/"))] = v
         elif rest == "pending":
@@ -795,6 +1047,8 @@ def _ns_export(ns: str, mailbox, op, state: "_StepState"):
             out[f"{ns}chain:{_pat_key(p)}"] = v
         for (d, p), v in state.nbr.items():
             out[f"{ns}nbr:{d}:{_pat_key(p)}"] = v
+        for k, v in state.fold.items():
+            out[f"{ns}fold:{k}"] = v
         for p, v in state.naive_req.items():
             out[f"{ns}req:{_pat_key(p)}"] = v
         if pending:

@@ -30,10 +30,11 @@ from repro.core.codegen import (
     exec_plan_part,
     frontier_count,
     make_stop_fn,
+    needs_weight_bounds,
     plan_scope,
 )
 from repro.core.plan import ByteCostModel, SCHEDULES, lower_step
-from repro.graph.structure import with_segment_ends
+from repro.graph.structure import with_segment_ends, with_weight_bounds
 from repro.trace import counted, span
 
 # pre-order Iter list — the shared iteration-counter index order
@@ -63,7 +64,9 @@ class CompiledProgram:
     # per-op expansion for A/B comparisons
     fuse: bool = True
     #: edge reductions of the last traced ``fn`` by the path they took,
-    #: ``{"scan": k, "scatter": m}`` (``StepExecutor._reduce_edges``)
+    #: ``{"scan": k, "scatter": m}`` (``StepExecutor._reduce_edges``), and
+    #: under ``"fold"`` those of them whose neighbour-only filters folded
+    #: into the gathered value (``StepExecutor._edge_reduce``)
     edge_reduce_paths: Optional[Dict[str, int]] = dataclasses.field(
         default=None, init=False
     )
@@ -136,13 +139,15 @@ class CompiledProgram:
         of the same static shape): a closed-over graph would be embedded in
         the HLO as constants, which at chip scale exceeds the 2 GB
         serialization limit. Its edge reductions scan the sorted edges
-        where it carries run ends (``Graph.in_ends``/``out_ends``), as
+        where it carries run ends (``Graph.in_ends``/``out_ends``), and a
+        minimum or maximum of a neighbour value plus ``e.w`` folds its
+        neighbour-only filters where it carries ``weights_bounded``, as
         ``self.graph`` does; tracing sets :attr:`edge_reduce_paths`.
         """
         with counted("edge_reduce/") as paths, plan_scope():
             out = self._fn(fields, graph)
         self.edge_reduce_paths = {
-            "scan": paths["scan"], "scatter": paths["scatter"]
+            k: paths[k] for k in ("scan", "scatter", "fold")
         }
         return out
 
@@ -319,7 +324,10 @@ def compile_program(
     ``graph`` is a :class:`~repro.graph.structure.Graph`. Where it lacks
     the run ends of an ordering the program's edge lists read, they are
     computed once, on its device (the span ``segment_ends``), and
-    :attr:`CompiledProgram.graph` is the graph that holds them.
+    :attr:`CompiledProgram.graph` is the graph that holds them. So is the
+    bound of its live weights (``Graph.weights_bounded``), in the same
+    span, where a comprehension folds only if it holds
+    (:func:`repro.core.codegen.needs_weight_bounds`).
 
     ``fuse`` (default True) applies the §4.3 program-level optimizations
     (state merging + iteration fusion, :func:`repro.core.plan.fuse`) to the
@@ -342,7 +350,10 @@ def compile_program(
     }
     jax.block_until_ready(graph)  # the span times the ends alone
     with span("segment_ends"):
-        graph = jax.block_until_ready(with_segment_ends(graph, directions))
+        graph = with_segment_ends(graph, directions)
+        if needs_weight_bounds(prog):
+            graph = with_weight_bounds(graph)
+        graph = jax.block_until_ready(graph)
     n = graph.n_vertices
     fs: Dict[str, jax.ShapeDtypeStruct] = {
         HALTED: jax.ShapeDtypeStruct((n,), jnp.bool_)

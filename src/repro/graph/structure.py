@@ -24,13 +24,19 @@ Conventions
   scan the sorted slots instead of scattering
   (:func:`repro.graph.ops.sorted_segment_reduce`). ``None`` where not
   computed: :func:`with_segment_ends` fills them.
+* ``weights_bounded`` is a static fact about the live weights of both
+  orderings: ``(every one is > -inf, every one is < +inf)`` (a NaN is
+  neither). A minimum of ``x + e.w`` may fold a filter into ``x`` as
+  ``+inf`` only where no live ``e.w`` is ``-inf`` or NaN, so that
+  ``inf + e.w`` is ``inf`` (``repro.core.codegen``). ``None`` where not
+  computed: :func:`with_weight_bounds` fills it.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +66,11 @@ class Graph:
     # run ends (module doc), i32[n_vertices] each, or None
     in_ends: Optional[jax.Array] = None  # in the dst ordering
     out_ends: Optional[jax.Array] = None  # in the t_src ordering
+
+    # the live weights' bounds (module doc), or None
+    weights_bounded: Optional[Tuple[bool, bool]] = dataclasses.field(
+        default=None, metadata=dict(static=True)
+    )
 
     @property
     def sentinel(self) -> int:
@@ -124,6 +135,30 @@ def with_segment_ends(graph: Graph, directions) -> Graph:
     if graph.out_ends is None and "out" in directions:
         ends["out_ends"] = _run_ends(graph.t_src, graph.n_vertices)
     return dataclasses.replace(graph, **ends) if ends else graph
+
+
+@jax.jit
+def _weight_bounds(weight, mask, t_weight, t_mask):
+    def live(w, m, ok):
+        return jnp.all(jnp.where(m, ok(w), True))
+
+    return tuple(
+        jnp.logical_and(live(weight, mask, ok), live(t_weight, t_mask, ok))
+        for ok in (lambda w: w > -jnp.inf, lambda w: w < jnp.inf)
+    )
+
+
+def with_weight_bounds(graph: Graph) -> Graph:
+    """``graph`` with :attr:`Graph.weights_bounded`, computed on the
+    graph's device and read back once where it is missing."""
+    if graph.weights_bounded is not None:
+        return graph
+    below, above = jax.device_get(_weight_bounds(
+        graph.weight, graph.edge_mask, graph.t_weight, graph.t_mask
+    ))
+    return dataclasses.replace(
+        graph, weights_bounded=(bool(below), bool(above))
+    )
 
 
 def stable_argsort(key) -> np.ndarray:

@@ -56,6 +56,7 @@ from repro.core.codegen import (
     HALTED,
     StepExecutor,
     _RemoteMsg,
+    _StepState,
     frontier_count,
     make_stop_fn,
     plan_scope,
@@ -101,7 +102,8 @@ class _StagedStep:
     fused dense trace intentionally omits (its ``push_request`` op is
     compute-free). The replicated mailbox keys here are therefore a
     superset of codegen's; keep the two protocols in sync when adding op
-    kinds or buffer classes.
+    kinds or buffer classes. A round's per-edge buffers, a fold's ``T``
+    among them, are the step executor's own (``StepExecutor.run_ops``).
     """
 
     def __init__(
@@ -134,6 +136,9 @@ class _StagedStep:
 
     def _nkey(self, direction, pattern) -> str:
         return f"{self.ns}nbr:{direction}:" + "/".join(pattern)
+
+    def _fkey(self, key) -> str:
+        return f"{self.ns}fold:{key}"
 
     # -- read supersteps -----------------------------------------------------
     def read_stage_fns(self):
@@ -200,7 +205,8 @@ class _StagedStep:
             # value to the requester; "push_reply": one combined reply per
             # distinct owner, fanned out to its requesters (the gather),
             # with the request set segment-combined per owner;
-            # "nbr_send": per-edge buffers
+            # "nbr_send": per-edge buffers, the step executor's (a fold's
+            # T in place of the reads only it consumes)
             out = dict(mailbox)
             with jax.named_scope("chain"):
                 self._reply(fields, out, _op)
@@ -211,11 +217,17 @@ class _StagedStep:
                 prefix = self.ns + "pushaddr:"
                 for k in [k for k in out if k.startswith(prefix)]:
                     out.pop(k)
-            with jax.named_scope("nbr"):
-                for direction, npat in _op.nbr_sends:
-                    nbr, _, _, _ = graph.edges(direction)
-                    val = self._lookup(fields, out, npat)
-                    out[self._nkey(direction, npat)] = gops.gather(val, nbr)
+            chains = {
+                p: out[self._key(p)]
+                for p in self.plan.materialized
+                if self._key(p) in out
+            }
+            ex = StepExecutor(self.step, graph, plan=self.plan)
+            _, state = ex.run_ops(fields, [_op], _StepState(chain=chains))
+            for (direction, npat), v in state.nbr.items():
+                out[self._nkey(direction, npat)] = v
+            for k, v in state.fold.items():
+                out[self._fkey(k)] = v
             return out
 
         return stage
@@ -287,6 +299,11 @@ class _StagedStep:
                 for d, p in self.info.nbr_comms
                 if self._nkey(d, p) in mailbox
             }
+            fold_prefix = self._fkey("")  # a fold's T, keyed by its ordinal
+            fold_values = {
+                int(k[len(fold_prefix):]): v
+                for k, v in mailbox.items() if k.startswith(fold_prefix)
+            }
             # the step's read buffers are consumed here; drop them so the
             # mailbox keyset is loop-stable (fused bodies re-create the
             # prefetched entries at iteration end)
@@ -298,13 +315,16 @@ class _StagedStep:
             if has_ru:
                 new, pending = ex(
                     fields, chain_values, split_remote=True,
-                    nbr_values=nbr_values,
+                    nbr_values=nbr_values, fold_values=fold_values,
                 )
                 out[pending_key] = tuple(
                     (m.idx, m.values, m.mask) for m in pending
                 )
                 return new, out
-            return ex(fields, chain_values, nbr_values=nbr_values), out
+            return ex(
+                fields, chain_values, nbr_values=nbr_values,
+                fold_values=fold_values,
+            ), out
 
         return main
 

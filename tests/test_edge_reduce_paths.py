@@ -52,11 +52,12 @@ def test_a_graph_without_ends_gets_them_and_scans(name, seed):
     out, trips, _ = cp.run()
     assert cp.edge_reduce_paths["scan"] >= 1
     assert cp.edge_reduce_paths["scatter"] == 0
-    # the same program on the graph without ends takes the scatter
+    # the same program on the graph without ends takes the scatter, and
+    # without the weights' bound SSSP keeps its filter per edge
     fields = cp.init_fields()
     scatter, scatter_trips, _ = cp.fn(fields, g)
     assert cp.edge_reduce_paths == {
-        "scan": 0, "scatter": cp.edge_reduce_paths["scatter"]
+        "scan": 0, "scatter": cp.edge_reduce_paths["scatter"], "fold": 0
     } and cp.edge_reduce_paths["scatter"] >= 1
     _same_fields(out, scatter)
     assert trips == np.asarray(scatter_trips).tolist()
@@ -71,7 +72,8 @@ def test_a_float_sum_over_edges_keeps_the_scatter():
     cp = compile_program(alg.PAGERANK, g)
     assert cp.graph.in_ends is not None and cp.graph.out_ends is not None
     cp.run()
-    assert cp.edge_reduce_paths == {"scan": 1, "scatter": 1}
+    # the sum's filter Deg[e.id] > 0 folds into the value it gathers
+    assert cp.edge_reduce_paths == {"scan": 1, "scatter": 1, "fold": 1}
 
 
 @pytest.mark.parametrize("name", ["sv", "wcc", "sssp"])

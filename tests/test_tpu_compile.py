@@ -86,13 +86,15 @@ def _graph_shapes(n_edges, sharding, n_vertices=N) -> Graph:
     def arr(dtype):
         return _sds((n_edges,), dtype, sharding)
 
-    # with run ends, as compile_program completes a graph: edge reductions
-    # take the segmented scan
+    # with run ends and the bound of finite weights, as compile_program
+    # completes a graph: edge reductions take the segmented scan, and
+    # SSSP's filter A[e.id] folds into the value it gathers
     ends = _sds((n_vertices,), i32, sharding)
     return Graph(
         src=arr(i32), dst=arr(i32), weight=arr(f32), edge_mask=arr(b),
         t_src=arr(i32), t_dst=arr(i32), t_weight=arr(f32), t_mask=arr(b),
         n_vertices=n_vertices, n_edges=n_edges, in_ends=ends, out_ends=ends,
+        weights_bounded=(True, True),
     )
 
 
@@ -115,6 +117,7 @@ def test_dense_program_compiles_with_graph_argument(one_chip, name, n_edges):
     )
     assert cp.edge_reduce_paths["scan"] >= 1
     assert cp.edge_reduce_paths["scatter"] == 0
+    assert cp.edge_reduce_paths["fold"] == (name == "sssp")
     mem = compiled.memory_analysis()
     # the edge arrays arrive as arguments (src, dst, mask at least)
     assert mem.argument_size_in_bytes >= 9 * n_edges
@@ -178,7 +181,8 @@ def test_every_op_of_a_benchmark_program_is_named(
         jax.jit(cp.fn).lower(fields, graph).compile().as_text()
     )
     assert cp.edge_reduce_paths == {
-        "scan": cp.edge_reduce_paths["scan"], "scatter": 0
+        "scan": cp.edge_reduce_paths["scan"], "scatter": 0,
+        "fold": int(program == "sssp_rooted"),
     }
 
     def trivial(opcode, calls):
