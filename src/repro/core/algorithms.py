@@ -13,10 +13,9 @@ user program. Coverage vs the paper's Table:
 | Approx. Max Weight Matching (MWM) | ``MWM`` (uses chain access + stop) |
 | Maximal Independent Set / Coloring| ``MIS`` (Luby-style, priority input) |
 | Strongly Connected Components     | ``SCC`` (fwd/bwd label propagation) |
-| Triangle Counting (TC)            | not DSL-expressible without list-valued
-|                                   | messages (needs the FFI the paper
-|                                   | mentions); provided as a substrate op in
-|                                   | ``repro.graph`` instead — see DESIGN.md |
+| Triangle Counting (TC)            | unsupported: not DSL-expressible without
+|                                   | list-valued messages (needs the FFI the
+|                                   | paper mentions) |
 | Bi-Connected Components (BCC)     | needs vertex addition — unsupported in
 |                                   | Palgol (paper §5.2), as in the paper |
 

@@ -75,7 +75,7 @@ class CompiledProgram:
         self, schedule: Optional[str] = None
     ) -> List[tuple]:
         """``(step, StepPlan)`` for every Step node, in program order —
-        what ``fn`` folds into the trace (dry-run / benchmark surface)."""
+        what ``fn`` folds into the trace."""
         sched = (
             schedule if schedule is not None else self.schedule
         ) or "pull"

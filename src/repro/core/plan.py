@@ -516,7 +516,7 @@ def plan_score(plan: StepPlan, costs: Optional[ByteCostModel]) -> Tuple:
 
 def program_plan_records(step_plans, costs: Optional[ByteCostModel] = None):
     """JSON-ready records for ``CompiledProgram.step_plans()`` output — the
-    one rendering the benchmark report and the partition dry-run share.
+    rendering ``tests/test_plan_counts.py`` pins.
     With a :class:`ByteCostModel`, each record also carries the modeled
     per-execution wire bytes."""
     out = []

@@ -1,8 +1,7 @@
-"""Distribution layer: path-keyed sharding rules.
+"""Distribution layer: the vertex-partition shardings of a partitioned job.
 
-``repro.dist.sharding`` is the only module that names mesh axes for data
-parallelism; everything else tags dimensions with its logical axes
-(``ALL``, ``BATCH``) or asks it for param/batch shardings by family.
+``repro.dist.sharding`` names the one mesh axis (``SHARD``) a partitioned
+Palgol job runs over, and places a ``PartitionedGraph`` and its fields on it.
 """
 
 from repro.dist import sharding  # noqa: F401

@@ -1,8 +1,9 @@
 """Graph substrate: dense, statically-shaped graph representation + segment ops.
 
 This layer is shared by the Pregel runtime (``repro.pregel``), the Palgol
-compiler's generated code (``repro.core.codegen``), and the GNN model zoo
-(``repro.models.gnn``). Everything here is pure JAX and jit/pjit friendly.
+compiler's generated code (``repro.core.codegen``) and the partitioned
+placement (``repro.graph.partition``). Everything here is pure JAX and jit
+friendly.
 """
 
 from repro.graph.structure import Graph, from_edge_list, symmetrize, pad_edges
@@ -10,7 +11,6 @@ from repro.graph.ops import (
     segment_reduce,
     gather,
     scatter_combine,
-    edge_softmax,
     out_degrees,
     in_degrees,
     COMBINE_IDENTITY,
@@ -24,7 +24,6 @@ __all__ = [
     "segment_reduce",
     "gather",
     "scatter_combine",
-    "edge_softmax",
     "out_degrees",
     "in_degrees",
     "COMBINE_IDENTITY",

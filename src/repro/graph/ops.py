@@ -282,202 +282,6 @@ def scatter_combine(
     raise ValueError(f"unknown combiner {op!r}")
 
 
-def edge_softmax(
-    scores: jax.Array,
-    segment_ids: jax.Array,
-    num_segments: int,
-    mask: Optional[jax.Array] = None,
-    indices_are_sorted: bool = False,
-) -> jax.Array:
-    """Numerically-stable softmax over edges grouped by destination (GAT)."""
-    if mask is not None:
-        mshape = mask.shape + (1,) * (scores.ndim - mask.ndim)
-        scores = jnp.where(mask.reshape(mshape), scores, -jnp.inf)
-    seg_max = segment_reduce(
-        scores, segment_ids, num_segments, "max", indices_are_sorted
-    )
-    seg_max = jnp.where(jnp.isfinite(seg_max), seg_max, 0.0)
-    ex = jnp.exp(scores - seg_max[segment_ids])
-    if mask is not None:
-        ex = jnp.where(mask.reshape(mshape), ex, 0.0)
-    denom = segment_reduce(ex, segment_ids, num_segments, "sum", indices_are_sorted)
-    return ex / jnp.maximum(denom[segment_ids], 1e-16)
-
-
-# ---------------------------------------------------------------------------
-# mesh-aware message passing (shard_map): GSPMD cannot partition the
-# arbitrary-destination scatters/gathers of graph aggregation (it replicates
-# the [E, D] update tensors — hundreds of GB on ogb_products). Under an
-# active mesh these wrappers run the gather/scatter *locally* per edge shard
-# with replicated node state, and reduce partials with one collective:
-#
-#   mp_gather          node[N,D] (replicated) × idx[E](sharded) → edge-local
-#   mp_segment_reduce  edge-local values → local partial [N,D] → psum/pmax
-#
-# This is vertex-cut partitioning with replicated vertex state — the same
-# scheme PowerGraph-style systems use for power-law graphs (DESIGN.md §2).
-
-
-def _mp_mesh():
-    from repro.dist import sharding as shd
-
-    mesh = shd._ACTIVE_MESH
-    if mesh is None:
-        return None, (), 1
-    # GNN message passing flattens the WHOLE mesh: edges are the only large
-    # dimension, so 1-D partitioning over all chips maximizes headroom
-    daxes = tuple(a for a in ("pod", "data", "model") if a in mesh.shape)
-    n_data = 1
-    for a in daxes:
-        n_data *= mesh.shape[a]
-    return mesh, daxes, n_data
-
-
-def _dspec(daxes):
-    return daxes if len(daxes) > 1 else (daxes[0] if daxes else None)
-
-
-def _pad_rows(x: jax.Array, n_rows: int, fill) -> jax.Array:
-    """Pad the leading dim up to ``n_rows`` with a constant."""
-    pad = n_rows - x.shape[0]
-    if pad == 0:
-        return x
-    widths = ((0, pad),) + ((0, 0),) * (x.ndim - 1)
-    return jnp.pad(x, widths, constant_values=fill)
-
-
-def mp_gather(field: jax.Array, idx: jax.Array, fill=None) -> jax.Array:
-    """Edge-sharded gather of (replicated) node state.
-
-    An edge count the mesh does not divide is padded up with masked
-    sentinel rows (and the result sliced back) — the mesh path must never
-    silently fall back to the single-device gather just because ``E`` is
-    odd (that fallback replicates the ``[E, D]`` tensors GSPMD cannot
-    partition, the exact failure this wrapper exists to avoid).
-    """
-    mesh, daxes, n_data = _mp_mesh()
-    if mesh is None or n_data == 1:
-        return gather(field, idx, fill)
-    from jax.sharding import PartitionSpec as P
-
-    e = idx.shape[0]
-    e_pad = -(-e // n_data) * n_data
-    idx_p = _pad_rows(idx, e_pad, 0)  # pad rows gather row 0, sliced off
-
-    d = _dspec(daxes)
-
-    def local(f, i):
-        return gather(f, i, fill)
-
-    out_ndim = field.ndim - 1 + idx.ndim
-    out = jax.shard_map(
-        local,
-        mesh=mesh,
-        in_specs=(P(*(None,) * field.ndim), P(d)),
-        out_specs=P(d, *(None,) * (out_ndim - 1)),
-        check_vma=False,
-    )(field, idx_p)
-    return out[:e] if e_pad != e else out
-
-
-def _diff_pminmax(part: jax.Array, daxes, is_max: bool) -> jax.Array:
-    """Differentiable cross-shard max/min: pmax/pmin have no VJP, so route
-    the cotangent to the shards attaining the extremum (split across ties),
-    matching jnp.max's subgradient convention."""
-
-    @jax.custom_vjp
-    def f(x):
-        return jax.lax.pmax(x, daxes) if is_max else jax.lax.pmin(x, daxes)
-
-    def fwd(x):
-        m = f(x)
-        return m, (x, m)
-
-    def bwd(res, g):
-        x, m = res
-        hit = (x == m).astype(g.dtype)
-        cnt = jnp.maximum(jax.lax.psum(hit, daxes), 1.0)
-        return (g * hit / cnt,)
-
-    f.defvjp(fwd, bwd)
-    return f(part)
-
-
-def mp_segment_reduce(
-    values: jax.Array,
-    segment_ids: jax.Array,
-    num_segments: int,
-    op: str = "sum",
-    mask: Optional[jax.Array] = None,
-) -> jax.Array:
-    """Edge-sharded segment reduction → replicated node result.
-
-    Odd edge counts are padded to mesh divisibility with masked sentinel
-    rows (``segment_id = num_segments`` is dropped by the scatter) instead
-    of abandoning the mesh path.
-    """
-    mesh, daxes, n_data = _mp_mesh()
-    if mesh is None or n_data == 1:
-        return segment_reduce(values, segment_ids, num_segments, op, mask=mask)
-    from jax.sharding import PartitionSpec as P
-
-    d = _dspec(daxes)
-    if mask is None:
-        mask = jnp.ones(values.shape[:1], jnp.bool_)
-    e = values.shape[0]
-    e_pad = -(-e // n_data) * n_data
-    if e_pad != e:
-        values = _pad_rows(values, e_pad, 0)
-        segment_ids = _pad_rows(segment_ids, e_pad, num_segments)
-        mask = _pad_rows(mask, e_pad, False)
-
-    def local(v, s, m):
-        part = segment_reduce(v, s, num_segments, op, mask=m)
-        if op in ("sum", "prod"):
-            return jax.lax.psum(part, daxes)
-        if op == "max":
-            return _diff_pminmax(part, daxes, True)
-        if op == "min":
-            return _diff_pminmax(part, daxes, False)
-        if op == "or":
-            return jax.lax.pmax(part.astype(jnp.int32), daxes).astype(jnp.bool_)
-        if op == "and":
-            return jax.lax.pmin(part.astype(jnp.int32), daxes).astype(jnp.bool_)
-        raise ValueError(op)
-
-    out_ndim = values.ndim
-    return jax.shard_map(
-        local,
-        mesh=mesh,
-        in_specs=(P(d, *(None,) * (values.ndim - 1)), P(d), P(d)),
-        out_specs=P(*(None,) * out_ndim),
-        check_vma=False,
-    )(values, segment_ids, mask)
-
-
-def mp_edge_softmax(
-    scores: jax.Array,
-    segment_ids: jax.Array,
-    num_segments: int,
-    mask: Optional[jax.Array] = None,
-) -> jax.Array:
-    """Numerically-stable softmax over edges grouped by destination,
-    composed from the mesh-aware primitives (which pad odd edge counts to
-    mesh divisibility internally)."""
-    mesh, daxes, n_data = _mp_mesh()
-    if mesh is None or n_data == 1:
-        return edge_softmax(scores, segment_ids, num_segments, mask=mask)
-    seg_max = mp_segment_reduce(scores, segment_ids, num_segments, "max",
-                                mask=mask)
-    seg_max = jnp.where(jnp.isfinite(seg_max), seg_max, 0.0)
-    ex = jnp.exp(scores - mp_gather(seg_max, segment_ids))
-    if mask is not None:
-        mshape = mask.shape + (1,) * (scores.ndim - mask.ndim)
-        ex = jnp.where(mask.reshape(mshape), ex, 0.0)
-    denom = mp_segment_reduce(ex, segment_ids, num_segments, "sum")
-    return ex / jnp.maximum(mp_gather(denom, segment_ids), 1e-16)
-
-
 def in_degrees(graph) -> jax.Array:
     ones = graph.edge_mask.astype(jnp.int32)
     return jax.ops.segment_sum(

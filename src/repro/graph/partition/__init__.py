@@ -1,9 +1,8 @@
 """Partitioned vertex state with halo exchange (`repro.graph.partition`).
 
 Ends the replicated-state scaling wall: instead of every chip holding every
-vertex field (the vertex-cut-over-edges scheme of ``repro.graph.ops``), the
-vertex id space is split into contiguous, edge-balanced ranges — one per
-shard — and each superstep moves only *boundary* state:
+vertex field, the vertex id space is split into contiguous, edge-balanced
+ranges — one per shard — and each superstep moves only *boundary* state:
 
 * :mod:`~repro.graph.partition.partitioner` — the edge-balanced greedy
   prefix-split partitioner and the :class:`PartitionedGraph` pytree
@@ -22,8 +21,8 @@ shard — and each superstep moves only *boundary* state:
   ``run_bsp_partitioned``: the ``placement="partitioned"`` path of
   ``repro.pregel.run_bsp``, executing unchanged Palgol programs over the
   partitioned layout;
-* :mod:`~repro.graph.partition.stats` — communication accounting feeding
-  ``benchmarks/palgol_mesh.py``, and ``byte_cost_model`` — the measured
+* :mod:`~repro.graph.partition.stats` — communication accounting
+  (``comm_bytes_report``), and ``byte_cost_model`` — the measured
   halo/request-set figures instrumented into a
   :class:`repro.core.plan.ByteCostModel` for the byte-aware ``auto``
   schedule selector.

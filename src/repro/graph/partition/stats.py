@@ -1,9 +1,9 @@
 """Communication accounting: replicated vs partitioned bytes per superstep.
 
-The replicated scheme (``repro.graph.ops`` mp_* path) keeps every vertex
-field on every chip; a superstep's neighbor aggregation produces a full
-``[N]`` partial per shard that one ring all-reduce combines — each device
-moves ``2·(S-1)/S·N`` values regardless of how local the graph is.
+The replicated scheme keeps every vertex field on every chip; a
+superstep's neighbor aggregation produces a full ``[N]`` partial per shard
+that one ring all-reduce combines — each device moves ``2·(S-1)/S·N``
+values regardless of how local the graph is.
 
 The partitioned scheme moves only the halo: each ghost value travels once
 from its owner to each reader. Two figures are reported —
@@ -14,8 +14,7 @@ from its owner to each reader. Two figures are reported —
   (``S² · pair_cap`` values), the honest figure for this implementation.
 
 Both are per *one f32-field pull superstep*; multiply by live field count
-and dtype width for a program-level estimate. ``benchmarks/palgol_mesh.py``
-serializes this report to ``BENCH_palgol_mesh.json``.
+and dtype width for a program-level estimate.
 """
 
 from __future__ import annotations
@@ -145,7 +144,7 @@ def byte_cost_model(
       same halo payload (remote writes in the stdlib target neighbors or
       chain endpoints, both boundary-shaped);
     * ``request_set`` — live requesters per dynamic chain round. Defaults
-      to ``n_vertices`` (every vertex reads its chain — the dense dryrun
+      to ``n_vertices`` (every vertex reads its chain — the dense
       regime); pass a measured active-set size (e.g. the frontier of a
       converging pointer-jumping round, or ``halo_total`` for a
       boundary-only access pattern) to model the sparse regimes where

@@ -27,10 +27,24 @@ def _setup(name, seed):
         fields = {"D": jnp.asarray(rng.integers(0, 30, 30), jnp.int32)}
     else:
         g = G.erdos_renyi(40, 3.0, directed=False, weighted=True, seed=seed)
+        if name == "kcore":
+            fields = {"K": jnp.full((g.n_vertices,), 2, jnp.int32)}
     return g, fields
 
 
-ALGS = ["sssp", "sv", "wcc", "mis", "bipartite_matching", "mwm", "chain4"]
+ALGS = [
+    "sssp",
+    "sv",
+    "wcc",
+    "mis",
+    "bipartite_matching",
+    "mwm",
+    "chain4",
+    "pagerank",
+    "scc",
+    "bfs",
+    "kcore",
+]
 
 
 @pytest.mark.parametrize("name", ALGS)

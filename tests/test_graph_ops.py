@@ -11,7 +11,6 @@ from hypothesis import strategies as st
 
 from repro.graph import generators as G
 from repro.graph import ops as gops
-from repro.graph.sampler import CSR, sample_khop
 from repro.graph.structure import from_edge_list, with_segment_ends
 
 
@@ -224,58 +223,8 @@ def test_with_segment_ends_fills_only_what_is_read():
     assert with_segment_ends(g, {"in", "out"}) is g
 
 
-def test_edge_softmax_normalizes():
-    g = G.erdos_renyi(50, 5.0, seed=1)
-    rng = np.random.default_rng(0)
-    scores = jnp.asarray(rng.normal(size=g.n_edges).astype(np.float32))
-    sm = gops.edge_softmax(
-        scores, g.dst, g.n_vertices, mask=g.edge_mask, indices_are_sorted=True
-    )
-    sums = gops.segment_reduce(
-        sm, g.dst, g.n_vertices, "sum", indices_are_sorted=True, mask=g.edge_mask
-    )
-    deg = np.asarray(gops.in_degrees(g))
-    s = np.asarray(sums)
-    assert np.all((np.abs(s - 1) < 1e-5) | (deg == 0))
-
-
 def test_symmetrize_produces_symmetric_graph():
     g = G.erdos_renyi(40, 4.0, directed=False, seed=2)
     src, dst, m = map(np.asarray, (g.src, g.dst, g.edge_mask))
     edges = set(zip(src[m].tolist(), dst[m].tolist()))
     assert all((d, s) in edges for s, d in edges)
-
-
-class TestSampler:
-    def test_khop_shapes_static(self):
-        g = G.erdos_renyi(100, 6.0, seed=3)
-        csr = CSR.from_graph(g)
-        seeds = jnp.arange(8)
-        blocks = sample_khop(csr, seeds, [5, 3], jax.random.PRNGKey(0))
-        assert blocks[0].neighbors.shape == (8, 5)
-        assert blocks[1].neighbors.shape == (40, 3)
-
-    def test_sampled_neighbors_are_real_neighbors(self):
-        g = G.erdos_renyi(60, 5.0, seed=4)
-        csr = CSR.from_graph(g)
-        seeds = jnp.arange(10)
-        (blk,) = sample_khop(csr, seeds, [7], jax.random.PRNGKey(1))
-        indptr = np.asarray(csr.indptr)
-        indices = np.asarray(csr.indices)
-        nbrs = np.asarray(blk.neighbors)
-        mask = np.asarray(blk.mask)
-        for i, v in enumerate(range(10)):
-            true_nbrs = set(indices[indptr[v]:indptr[v + 1]].tolist())
-            for j in range(7):
-                if mask[i, j]:
-                    assert nbrs[i, j] in true_nbrs
-                else:
-                    assert nbrs[i, j] == g.n_vertices
-
-    def test_zero_degree_masked(self):
-        g = from_edge_list(np.array([0], np.int32), np.array([1], np.int32), 4)
-        csr = CSR.from_graph(g)
-        (blk,) = sample_khop(csr, jnp.arange(4), [3], jax.random.PRNGKey(2))
-        mask = np.asarray(blk.mask)
-        assert mask[1].all()  # vertex 1 has in-neighbor 0
-        assert not mask[0].any() and not mask[2].any() and not mask[3].any()

@@ -216,11 +216,23 @@ class TestPartitionedExecutionSingleShard:
 
     @pytest.mark.parametrize(
         "name",
-        ["sssp", "wcc", "sv", "mwm", "chain4", "mis", "bipartite_matching"],
+        [
+            "sssp",
+            "wcc",
+            "sv",
+            "mwm",
+            "chain4",
+            "mis",
+            "bipartite_matching",
+            "pagerank",
+            "scc",
+            "bfs",
+            "kcore",
+        ],
     )
     def test_matches_dense(self, name):
         fields = None
-        if name == "sssp":
+        if name in ("sssp", "pagerank", "scc"):
             g = G.erdos_renyi(40, 4.0, directed=True, weighted=True, seed=3)
         elif name == "chain4":
             g = G.erdos_renyi(30, 2.0, directed=False, seed=3)
@@ -235,6 +247,8 @@ class TestPartitionedExecutionSingleShard:
             fields = {"Side": jnp.asarray(side)}
         else:
             g = G.erdos_renyi(40, 3.0, directed=False, weighted=True, seed=3)
+            if name == "kcore":
+                fields = {"K": jnp.full((40,), 2, jnp.int32)}
         cp = compile_program(alg.ALL[name], g, initial_fields=fields)
         dense, _, counts = cp.run(fields)
         f0 = cp.init_fields(fields)
@@ -297,17 +311,14 @@ class TestCommBytes:
         )
 
     def test_benchmark_report_shape(self):
-        """The benchmark's comm_comparison (what writes
-        BENCH_palgol_mesh.json) carries both layouts for every graph."""
-        root = str(Path(__file__).resolve().parent.parent)
-        sys.path.insert(0, root)
-        try:
-            from benchmarks.palgol_mesh import comm_comparison
-        finally:
-            sys.path.remove(root)
-        bench = comm_comparison(4)
-        assert bench["n_shards"] == 4
-        for rec in bench["per_graph"].values():
+        """comm_bytes_report carries both layouts on a range-local grid and
+        on an R-MAT power-law graph."""
+        graphs = (
+            G.grid2d(512, 8),
+            G.rmat(12, avg_degree=8.0, directed=True, seed=5),
+        )
+        for g in graphs:
+            rec = comm_bytes_report(g, 4)
             assert rec["replicated_bytes_per_superstep"] > 0
             assert rec["partitioned_padded_bytes_per_superstep"] > 0
 

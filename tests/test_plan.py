@@ -51,14 +51,23 @@ def _steps(src, g, fields=None):
 
 def _setup(name, seed=3):
     fields = None
-    if name == "sssp":
+    if name in ("sssp", "pagerank", "scc"):
         g = G.erdos_renyi(40, 4.0, directed=True, weighted=True, seed=seed)
+    elif name == "bipartite_matching":
+        g, side = G.random_bipartite(15, 15, 3.0, seed=seed)
+        fields = {"Side": jnp.asarray(side)}
+    elif name == "mis":
+        g = G.erdos_renyi(40, 3.0, directed=False, seed=seed)
+        rng = np.random.default_rng(seed)
+        fields = {"P": jnp.asarray(rng.random(g.n_vertices), jnp.float32)}
     elif name == "chain4":
         g = G.erdos_renyi(30, 2.0, directed=False, seed=seed)
         rng = np.random.default_rng(seed)
         fields = {"D": jnp.asarray(rng.integers(0, 30, 30), jnp.int32)}
     else:
         g = G.erdos_renyi(40, 3.0, directed=False, weighted=True, seed=seed)
+        if name == "kcore":
+            fields = {"K": jnp.full((g.n_vertices,), 2, jnp.int32)}
     return g, fields
 
 
@@ -305,7 +314,19 @@ class TestAutoSelector:
                 )
 
 
-MATRIX_ALGS = ["sssp", "wcc", "sv", "chain4"]
+MATRIX_ALGS = [
+    "sssp",
+    "wcc",
+    "sv",
+    "chain4",
+    "mis",
+    "bipartite_matching",
+    "mwm",
+    "pagerank",
+    "scc",
+    "bfs",
+    "kcore",
+]
 
 
 class TestExecutorScheduleMatrix:

@@ -1,4 +1,4 @@
-"""Compile the main-path programs and kernels for a described TPU v5e.
+"""Compile the main-path programs for a described TPU v5e.
 
 Nothing here runs on a chip. Every test compiles ahead of time, from
 shapes only, for a v5e topology described by
@@ -8,7 +8,6 @@ is described inside a fixture, never while a module is imported: only one
 process at a time may load the TPU library.
 """
 
-import functools
 import math
 import os
 import re
@@ -27,9 +26,6 @@ from repro.core import plan as plan_mod
 from repro.graph.partition.executor import _make_superstep_fn
 from repro.graph.partition.partitioner import HaloSpec, PartitionedGraph
 from repro.graph.structure import Graph, from_edge_list
-from repro.kernels.embedding_bag import embedding_bag_pallas
-from repro.kernels.gather_rows import gather_rows_pallas
-from repro.kernels.segment_reduce.kernel import segment_sum_ell_kernel
 
 #: Graph500 scale 20, edgefactor 16: directed, and symmetrised (twice that)
 SCALE = 20
@@ -37,13 +33,6 @@ N = 1 << SCALE
 E_DIR = 16 * N
 E_SYM = 2 * E_DIR
 V5E_HBM_BYTES = 16 * 1024**3
-
-_BLOCK_RULE = (
-    "The Pallas TPU lowering currently requires that the last two dimensions "
-    "of your block shape are divisible by 8 and 128 respectively, or be "
-    "equal to the respective dimensions of the overall array"
-)
-
 
 @pytest.fixture(scope="module")
 def topo():
@@ -254,42 +243,6 @@ def test_partitioned_sv_first_superstep_compiles(mesh4):
     assert "all-to-all" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
-
-
-@pytest.mark.parametrize("width", [1, 128])
-def test_segment_sum_ell_kernel_compiles(one_chip, width):
-    n_edges, nb, budget = 1 << 24, 256, 4096
-    kernel = functools.partial(
-        segment_sum_ell_kernel, n_blocks=n_edges // budget, nb=nb,
-        budget=budget,
-    )
-    compiled = jax.jit(kernel).lower(
-        _sds((n_edges,), jnp.int32, one_chip),
-        _sds((n_edges, width), jnp.float32, one_chip),
-    ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-@pytest.mark.xfail(
-    strict=True, raises=ValueError,
-    reason=f"gather_rows: its (1, d) row blocks are refused: {_BLOCK_RULE}",
-)
-def test_gather_rows_compiles(one_chip):
-    gather_rows_pallas.lower(
-        _sds((N, 128), jnp.float32, one_chip),
-        _sds((4096,), jnp.int32, one_chip),
-    ).compile()
-
-
-@pytest.mark.xfail(
-    strict=True, raises=ValueError,
-    reason=f"embedding_bag: its (1, hot) index blocks are refused: {_BLOCK_RULE}",
-)
-def test_embedding_bag_compiles(one_chip):
-    embedding_bag_pallas.lower(
-        _sds((N, 128), jnp.float32, one_chip),
-        _sds((256, 8), jnp.int32, one_chip),
-    ).compile()
 
 
 #: the sv-part4-g500-24 cell: Graph500 scale 24 over four chips, at the
