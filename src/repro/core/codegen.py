@@ -265,7 +265,8 @@ class _EdgeCtx:
     seg: jax.Array = None  # row index of the current vertex (segment key)
     nbr_read: jax.Array = None  # address for reading per-row arrays at e.id
     # i32[rows] one past each row's last slot of the seg-sorted edges
-    # (Graph.segment_ends); None where unknown (a partitioned comm)
+    # (Graph.segment_ends, or a shard's PartitionedGraph.in_ends/out_ends);
+    # None where the graph carries none
     ends: Optional[jax.Array] = None
 
     def __post_init__(self):

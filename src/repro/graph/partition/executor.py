@@ -61,7 +61,7 @@ from repro.graph.partition.partitioner import (
     split_rows,
 )
 from repro.pregel.runtime import BSPResult, walk_plan
-from repro.trace import summed
+from repro.trace import counted, summed
 
 AXIS = halo.AXIS
 
@@ -118,16 +118,20 @@ class ShardComm:
     def edge_ctx(self, direction: str) -> _EdgeCtx:
         pg = self.pg
         if direction in ("in", "nbr"):
-            seg, nbr_g, nbr_h, w, m = pg.dst_l, pg.src_g, pg.src_h, pg.w, pg.emask
+            seg, nbr_g, nbr_h, w, m, ends = (
+                pg.dst_l, pg.src_g, pg.src_h, pg.w, pg.emask, pg.in_ends,
+            )
         elif direction == "out":
-            seg, nbr_g, nbr_h, w, m = (
+            seg, nbr_g, nbr_h, w, m, ends = (
                 pg.t_src_l, pg.t_dst_g, pg.t_dst_h, pg.t_w, pg.t_emask,
+                pg.out_ends,
             )
         else:
             raise ValueError(f"unknown edge direction {direction!r}")
         vid = (self.start + seg).astype(jnp.int32)
         return _EdgeCtx(
-            direction, nbr=nbr_g, vid=vid, w=w, emask=m, seg=seg, nbr_read=nbr_h
+            direction, nbr=nbr_g, vid=vid, w=w, emask=m, seg=seg,
+            nbr_read=nbr_h, ends=ends,
         )
 
     def scatter_reduce(self, idx, values, op: str, mask) -> jax.Array:
@@ -150,7 +154,7 @@ class ShardComm:
 
 _SHARDED_PG_FIELDS = (
     "vmask", "src_g", "src_h", "dst_l", "w", "emask",
-    "t_dst_g", "t_dst_h", "t_src_l", "t_w", "t_emask",
+    "t_dst_g", "t_dst_h", "t_src_l", "t_w", "t_emask", "in_ends", "out_ends",
 )
 _SHARDED_HALO_FIELDS = ("ghost_ids", "send_local", "recv_pos")
 
@@ -250,6 +254,12 @@ class PartitionedProgram:
     :func:`~repro.graph.partition.partition_on_mesh` leaves them), else a
     1-D mesh over the first ``pg.n_shards`` devices. Fields go in and come
     out dense (``[N, ...]``), as with ``run_bsp``.
+
+    :attr:`edge_reduce_paths` tallies the edge reductions of every
+    superstep traced so far by the path each took (``{"scan": k,
+    "scatter": m, "fold": f}``, as ``CompiledProgram.edge_reduce_paths``):
+    an order-independent reduction scans the shard's sorted edges, which
+    carry run ends.
     """
 
     def __init__(
@@ -290,6 +300,7 @@ class PartitionedProgram:
         self._fns: Dict[int, object] = {}
         #: per superstep: bytes its collectives carry per chip per dispatch
         self._carried: Dict[int, Dict[str, float]] = {}
+        self._paths: collections.Counter = collections.Counter()
 
     def _dispatch(self, ss: plan_mod.Superstep, loops, flds, mailbox):
         key = id(ss)
@@ -297,11 +308,16 @@ class PartitionedProgram:
             return self._fns[key](flds, mailbox, self.pg)
         self._fns[key] = _make_superstep_fn(ss, self.pg, self.mesh, loops)
         # the first dispatch traces the superstep: its collectives record
-        # what their operands carry
-        with summed("comm/") as carried:
+        # what their operands carry, its edge reductions the path they take
+        with summed("comm/") as carried, counted("edge_reduce/") as paths:
             out = self._fns[key](flds, mailbox, self.pg)
         self._carried[key] = dict(carried)
+        self._paths.update(paths)
         return out
+
+    @property
+    def edge_reduce_paths(self) -> Dict[str, int]:
+        return {k: self._paths[k] for k in ("scan", "scatter", "fold")}
 
     def run(
         self, fields: Dict[str, jax.Array], max_iters: Optional[int] = None

@@ -7,8 +7,9 @@ the plain reference for it. Its input is a directed edge list laid out as
 ``k`` edges, live where ``mask``. Nothing of it is copied to the host or
 gathered on one chip. Three passes, each a ``shard_map`` over the mesh:
 
-1. *sizes* — vertex degrees, a ``psum`` of per-chip histograms; the
-   range boundaries by :func:`edge_balanced_ranges`'s rule (weight
+1. *sizes* — vertex in- and out-degrees, a ``psum`` of per-chip
+   histograms, kept on every chip for the route pass; the range
+   boundaries by :func:`edge_balanced_ranges`'s rule (weight
    1 + in-degree + out-degree, greedy prefix cut); per (chip, owner) edge
    counts of both orderings; and, for each reader shard, the bitmap of the
    vertices its edges read (a ``psum_scatter`` of per-chip bitmaps). The
@@ -19,7 +20,8 @@ gathered on one chip. Three passes, each a ``shard_map`` over the mesh:
    and to its source's owner (push ordering), one ``all_to_all`` each
    (:func:`route`), and each shard sorts its block by ``(key, other
    endpoint[, weight])`` (:func:`sort_blocks`, one executable for both
-   orderings);
+   orderings); a shard's run ends are the running count of its rows'
+   degrees, as the block is sorted by row (no scatter over the block);
 3. *halo* — the ghost list of each shard is the sorted nonzero set of
    its bitmap, less its own range (a static-size ``nonzero``); the
    halo-local remap reads a ghost's slot from the bitmap's running count,
@@ -137,8 +139,8 @@ def _sizes_body(src, dst, mask, *, n: int, n_shards: int):
         return jnp.zeros((n + 1,), jnp.int32).at[ids].add(1, mode="drop")[:n]
 
     with jax.named_scope("degrees"):
-        weight = 1 + jax.lax.psum(hist(dst) + hist(src), AXIS)
-    cum = jnp.cumsum(weight)
+        degrees = jax.lax.psum(jnp.stack([hist(dst), hist(src)]), AXIS)
+    cum = jnp.cumsum(1 + degrees[0] + degrees[1])
     total = cum[-1]
     q, r = total // S, total % S
     bounds = [jnp.int32(0)]
@@ -172,7 +174,7 @@ def _sizes_body(src, dst, mask, *, n: int, n_shards: int):
                                 for i in range(S + 1)]))
         rows.append(row[None])
     return (bounds, jnp.stack(counts)[None], jnp.stack(below)[None],
-            rows[0], rows[1])
+            tuple(rows), (degrees[0], degrees[1]))
 
 
 @functools.lru_cache(maxsize=None)
@@ -181,7 +183,7 @@ def _sizes_fn(mesh, n: int):
     return jax.jit(jax.shard_map(
         functools.partial(_sizes_body, n=n, n_shards=S), mesh=mesh,
         in_specs=(P(AXIS),) * 3,
-        out_specs=(P(), P(AXIS), P(AXIS), P(AXIS), P(AXIS)),
+        out_specs=(P(), P(AXIS), P(AXIS), (P(AXIS),) * 2, (P(),) * 2),
         check_vma=False,
     ))
 
@@ -271,9 +273,12 @@ def sorted_length(length: int) -> int:
     return round_up(length, SORT_BITS)
 
 
-def _finish_body(key, other, bounds, *w, n, e_max, v_max):
+def _finish_body(key, other, bounds, degree, *w, n, e_max, v_max):
     """A shard's sorted block → its first ``e_max`` slots in the padding
-    conventions of :class:`PartitionedGraph`, and its ``vmask`` row."""
+    conventions of :class:`PartitionedGraph`, its run ends and its
+    ``vmask`` row. ``degree[u]`` counts the live edges keyed ``u``: the
+    block is sorted by key, so a row's run ends where the running count
+    of its rows' degrees does."""
     k, o = key[:e_max], other[:e_max]
     m = k < n
     me = jax.lax.axis_index(AXIS)
@@ -281,7 +286,10 @@ def _finish_body(key, other, bounds, *w, n, e_max, v_max):
     key_l = jnp.where(m, k - start, v_max).astype(jnp.int32)
     wt = jnp.where(m, w[0][:e_max], 0.0) if w else m.astype(jnp.float32)
     vmask = jnp.arange(v_max) < bounds[me + 1] - start
-    return key_l[None], o[None], wt[None], m[None], vmask[None]
+    rows = jax.lax.dynamic_slice(jnp.pad(degree, (0, v_max)), (start,),
+                                 (v_max,))
+    ends = jnp.cumsum(jnp.where(vmask, rows, 0), dtype=jnp.int32)
+    return key_l[None], o[None], wt[None], m[None], ends[None], vmask[None]
 
 
 @functools.lru_cache(maxsize=None)
@@ -289,8 +297,8 @@ def _finish_fn(mesh, n, e_max, v_max, weighted):
     return jax.jit(jax.shard_map(
         functools.partial(_finish_body, n=n, e_max=e_max, v_max=v_max),
         mesh=mesh,
-        in_specs=(P(AXIS), P(AXIS), P()) + (P(AXIS),) * weighted,
-        out_specs=(P(AXIS),) * 5, check_vma=False,
+        in_specs=(P(AXIS), P(AXIS), P(), P()) + (P(AXIS),) * weighted,
+        out_specs=(P(AXIS),) * 6, check_vma=False,
     ))
 
 
@@ -375,7 +383,8 @@ def partition_on_mesh(
         w = jax.device_put(w, split)
     with span("partition"):
         with span("partition/sizes"):
-            bounds, counts, below, *rows = _sizes_fn(mesh, n)(src, dst, mask)
+            bounds, counts, below, rows, degrees = _sizes_fn(mesh, n)(
+                src, dst, mask)
             sizes = _sizes(*jax.device_get((bounds, counts, below)),
                            bits=size_bits)
         edges = {"src": src, "dst": dst}
@@ -386,21 +395,23 @@ def partition_on_mesh(
             finish_fn = _finish_fn(mesh, n, sizes.e_max, sizes.v_max,
                                    weighted)
             blocks = []
-            for key, other in _ORDERINGS:
+            for (key, other), degree in zip(_ORDERINGS, degrees):
                 recv = route_fn(edges[key], edges[other], mask, bounds,
                                 *((w,) if weighted else ()))
                 key_s, other_s, *w_s = sort_blocks(mesh, *recv)
-                blocks.append(finish_fn(key_s, other_s, bounds, *w_s))
+                blocks.append(finish_fn(key_s, other_s, bounds, degree,
+                                        *w_s))
             jax.block_until_ready(blocks)
         with span("partition/halo"):
             halos = []
             for o in range(2):
-                _, nbr_g, _, emask, _ = blocks[o]
+                _, nbr_g, _, emask, _, _ = blocks[o]
                 fn = _halo_fn(mesh, n, sizes.v_max, sizes.n_ghost[o],
                               sizes.pair_cap[o])
                 halos.append(fn(nbr_g, emask, rows[o], bounds))
             jax.block_until_ready(halos)
-    (dst_l, src_g, w_p, m_p, vmask), (tsrc_l, tdst_g, tw_p, tm_p, _) = blocks
+    ((dst_l, src_g, w_p, m_p, in_ends, vmask),
+     (tsrc_l, tdst_g, tw_p, tm_p, out_ends, _)) = blocks
 
     def spec(halo, o):
         _, ghost_ids, send_local, recv_pos = halo
@@ -412,7 +423,7 @@ def partition_on_mesh(
         starts=bounds, vmask=vmask,
         src_g=src_g, src_h=halos[0][0], dst_l=dst_l, w=w_p, emask=m_p,
         t_dst_g=tdst_g, t_dst_h=halos[1][0], t_src_l=tsrc_l, t_w=tw_p,
-        t_emask=tm_p,
+        t_emask=tm_p, in_ends=in_ends, out_ends=out_ends,
         halo_in=spec(halos[0], 0), halo_out=spec(halos[1], 1),
         n_vertices=n, n_edges=sizes.n_edges, n_shards=S,
         v_max=sizes.v_max, e_max=sizes.e_max,

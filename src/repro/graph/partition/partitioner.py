@@ -80,6 +80,11 @@ class PartitionedGraph:
     t_src_l: jax.Array  # i32[S, e_max]
     t_w: jax.Array  # f32[S, e_max]
     t_emask: jax.Array  # bool[S, e_max]
+    # run ends, the contract of Graph.in_ends/out_ends per shard: one past
+    # each local row's last slot of dst_l (in_ends) and t_src_l (out_ends);
+    # a row with no slot takes the end of the row before, or 0
+    in_ends: jax.Array  # i32[S, v_max]
+    out_ends: jax.Array  # i32[S, v_max]
     halo_in: HaloSpec  # ghosts read by the pull ordering (srcs)
     halo_out: HaloSpec  # ghosts read by the push ordering (dsts)
     # static metadata
@@ -217,6 +222,15 @@ def _shard_edges(key, other, w, mask, bounds, v_max):
     return key_l, oth_g, w_p, m_p, e_max
 
 
+def _run_ends(key_l: np.ndarray, v_max: int) -> np.ndarray:
+    """``i32[S, v_max]`` run ends of the ascending per-shard keys
+    ``key_l`` (padding ``v_max`` sorts last and ends no row)."""
+    rows = np.arange(v_max)
+    return np.stack(
+        [np.searchsorted(k, rows, side="right") for k in key_l]
+    ).astype(np.int32)
+
+
 def partition_graph(
     graph, n_shards: int, bounds: Optional[np.ndarray] = None
 ) -> PartitionedGraph:
@@ -281,6 +295,8 @@ def partition_graph(
         t_src_l=jnp.asarray(tsrc_l),
         t_w=jnp.asarray(tw_p),
         t_emask=jnp.asarray(tm_p),
+        in_ends=jnp.asarray(_run_ends(dst_l, v_max)),
+        out_ends=jnp.asarray(_run_ends(tsrc_l, v_max)),
         halo_in=halo_in,
         halo_out=halo_out,
         n_vertices=n,

@@ -177,6 +177,14 @@ class TestPartitionProperties:
         # edge conservation
         total = sum(int(np.asarray(pg.emask[s]).sum()) for s in range(n_shards))
         assert total == pg.n_edges
+        # run ends of both sorted orderings
+        rows = np.arange(pg.v_max)
+        for keys, ends in ((pg.dst_l, pg.in_ends), (pg.t_src_l, pg.out_ends)):
+            for s in range(n_shards):
+                k = np.asarray(keys[s])
+                assert np.all(np.diff(k) >= 0)
+                assert np.array_equal(
+                    np.asarray(ends[s]), np.searchsorted(k, rows, "right"))
         # round trip
         x = jnp.arange(n, dtype=jnp.int32)
         assert np.array_equal(
@@ -332,6 +340,7 @@ SUBPROCESS_TEST = textwrap.dedent(
     from repro.core import algorithms as alg, compile_program
     from repro.graph import generators as G
     from repro.pregel import run_bsp
+    from repro.trace import counted
 
     # bool ||= / &&= remote writes: the or/and scatter_reduce branch only
     # engages its collective transport with more than one shard
@@ -356,10 +365,12 @@ SUBPROCESS_TEST = textwrap.dedent(
 
     # sssp / wcc: the acceptance pair; sv + chain4: remote writes and
     # pull-mode pointer doubling across shards; mwm: argmax + stop/halted;
-    # bool_comb: or/and combiners
-    for name in ("sssp", "wcc", "sv", "chain4", "mwm", "bool_comb"):
+    # bool_comb: or/and combiners; bfs, scc (Out) and pagerank (a float
+    # sum, the one reduction here that keeps the scatter)
+    for name in ("sssp", "wcc", "sv", "chain4", "mwm", "bool_comb", "bfs",
+                 "scc", "pagerank"):
         fields = None
-        if name == "sssp":
+        if name in ("sssp", "scc", "pagerank", "bfs"):
             g = G.erdos_renyi(48, 4.0, directed=True, weighted=True, seed=3)
         elif name == "chain4":
             g = G.erdos_renyi(32, 2.0, directed=False, seed=3)
@@ -370,11 +381,15 @@ SUBPROCESS_TEST = textwrap.dedent(
         cp = compile_program(progs[name], g, initial_fields=fields)
         dense, _, counts = cp.run(fields)
         f0 = cp.init_fields(fields)
-        res = run_bsp(cp.prog, g, f0, schedule="pull",
-                      placement="partitioned")
+        with counted("edge_reduce/") as paths:
+            res = run_bsp(cp.prog, g, f0, schedule="pull",
+                          placement="partitioned")
         for f in dense:
             a, b = np.asarray(dense[f]), np.asarray(res.fields[f])
-            assert np.array_equal(a, b, equal_nan=True), (name, f)
+            assert a.dtype == b.dtype and a.tobytes() == b.tobytes(), (
+                name, f)
+        scatters = 1 if name == "pagerank" else 0
+        assert paths["scatter"] == scatters, (name, dict(paths))
         assert res.supersteps == counts["palgol_pull"], (
             name, res.supersteps, counts["palgol_pull"])
         print(name, "ok", res.supersteps)

@@ -219,6 +219,7 @@ def _partitioned_graph_shapes(mesh, n_shards) -> PartitionedGraph:
         w=blk(f32, e_max), emask=blk(b, e_max),
         t_dst_g=blk(i32, e_max), t_dst_h=blk(i32, e_max),
         t_src_l=blk(i32, e_max), t_w=blk(f32, e_max), t_emask=blk(b, e_max),
+        in_ends=blk(i32, v_max), out_ends=blk(i32, v_max),
         halo_in=halo(), halo_out=halo(),
         n_vertices=N, n_edges=E_SYM, n_shards=n_shards, v_max=v_max,
         e_max=e_max,
@@ -281,9 +282,11 @@ def test_partition_on_mesh_passes_fit_a_v5e_at_scale_24(mesh4, stage):
     bounds = _sds((S + 1,), i32, whole)
     length = on_mesh.sorted_length(max(S * p["cap"], p["e_max"]))
     block = _sds((S * length,), i32, split)  # a sorted block
-    # the edge list a chip was handed stays live through every pass, the
-    # pull ordering's blocks (13 B a slot) through the push ordering's
-    held = p["k"] * 9 + (0 if stage == "sizes" else p["e_max"] * 13)
+    # the edge list a chip was handed stays live through every pass; the
+    # sizing pass's degrees (8 B a vertex) and the pull ordering's blocks
+    # (13 B a slot, 4 B a row of run ends) through the push ordering's
+    held = p["k"] * 9 + (0 if stage == "sizes" else
+                         p["n"] * 8 + p["e_max"] * 13 + p["v_max"] * 4)
     if stage == "sizes":
         fn, args = on_mesh._sizes_fn(mesh4, p["n"]), edges
     elif stage == "route":
@@ -291,7 +294,7 @@ def test_partition_on_mesh_passes_fit_a_v5e_at_scale_24(mesh4, stage):
         args = edges + [bounds]
     elif stage == "finish":
         fn = on_mesh._finish_fn(mesh4, p["n"], p["e_max"], p["v_max"], False)
-        args = [block, block, bounds]
+        args = [block, block, bounds, _sds((p["n"],), i32, whole)]
     else:
         fn = on_mesh._halo_fn(mesh4, p["n"], p["v_max"], p["n_ghost"],
                               p["pair_cap"])
@@ -326,6 +329,7 @@ def test_partitioned_sv_supersteps_fit_a_v5e_at_scale_24(mesh4):
         w=blk(f32, e_max), emask=blk(b, e_max),
         t_dst_g=blk(i32, e_max), t_dst_h=blk(i32, e_max),
         t_src_l=blk(i32, e_max), t_w=blk(f32, e_max), t_emask=blk(b, e_max),
+        in_ends=blk(i32, v_max), out_ends=blk(i32, v_max),
         halo_in=halo(), halo_out=halo(), n_vertices=p["n"],
         n_edges=S * e_max, n_shards=S, v_max=v_max, e_max=e_max,
     )
